@@ -162,8 +162,7 @@ def sharded_coordinated_step(mesh, axis_name: str, *, num_metrics: int = 8):
 
     spec_p = ControllerSpec(*(P(axis_name) for _ in ControllerSpec._fields))
     state_p = ControllerState(P(axis_name))
-    from repro.distributed.sharding import shard_map
-    return shard_map(
+    return jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(spec_p, state_p, P(axis_name)),
         out_specs=(state_p, P(axis_name)),

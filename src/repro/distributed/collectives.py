@@ -8,31 +8,29 @@
   attention (o_i, m_i, l_i): the sequence-parallel KV path (DESIGN.md §6);
   math matches the Pallas decode kernel's scratch accumulators, so a shard's
   kernel output feeds this directly.
-* :func:`tp_segment_attention` / :func:`tp_paged_segment_attention` — the
-  serve engine's head-sharded segment-attention: the fused kernels run
-  per-shard over a contiguous head chunk on the ``model`` axis, the [P,H,D]
-  output is all-gathered back INSIDE the shard body (pure data movement —
-  no psum over a contraction — so the result is bit-identical to the
-  single-device op), and everything downstream runs replicated.  Falls back
-  to the plain op when no serving mesh is active or the head counts do not
-  divide the model axis (e.g. MQA kv_heads=1).
-
-``shard_map`` is imported through :mod:`repro.distributed.sharding`'s one
-version-compat alias (jax moved it out of experimental around 0.4.35) —
-do not duplicate the fallback here.
+* :func:`tp_segment_attention` / :func:`tp_paged_segment_attention` /
+  :func:`tp_paged_decode_attention` — the serve engine's head-sharded
+  attention: the fused kernels run per-shard over a contiguous head chunk
+  on the ``model`` axis, the output is all-gathered back INSIDE the shard
+  body (pure data movement — no psum over a contraction — so the result is
+  bit-identical to the single-device op), and everything downstream runs
+  replicated.  Falls back to the plain op when no serving mesh is active or
+  the head counts do not divide the model axis (e.g. MQA kv_heads=1).
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import current_mesh, shard_map
+from .sharding import current_mesh
 
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum_grads",
            "sp_decode_combine", "tp_segment_attention",
-           "tp_paged_segment_attention"]
+           "tp_paged_segment_attention", "tp_paged_decode_attention"]
 
 _BLOCK = 128
 
@@ -102,34 +100,38 @@ def _serve_tp_mesh(heads: int, kv_heads: int):
     return mesh
 
 
-def tp_segment_attention(q, k, v, q_pos, k_pos, q_seg, k_seg, *,
-                         window: int = 0):
-    """Head-sharded flat segment attention: q [P,H,D]; k,v [N,Kv,D].
+def _head_parallel(op, q, k, v, *index):
+    """``op(q, k, v, *index)`` with q's heads and k/v's Kv heads (axis 1 of
+    each) split over the ``model`` axis of the active serving mesh.
 
-    Per-shard the fused op sees a contiguous head chunk [P,H/s,D] x
-    [N,Kv/s,D]; the all-gather over ``model`` (axis 1, inside the body)
-    rebuilds the full [P,H,D] output on every shard.  ``check_rep=False``:
-    Pallas calls carry no replication rule, and the ``data`` axis is
-    untouched (all in_specs leave it out, so inputs and output are
-    replicated over it by construction)."""
-    from repro.kernels.segment_attention import segment_attention_op
+    Each shard runs ``op`` on a contiguous head chunk, and the all-gather
+    over ``model`` (axis 1, inside the body) rebuilds the whole output on
+    every shard.  The index operands (positions, segments, block tables)
+    are global and replicated.  ``check_vma=False``: Pallas calls carry no
+    replication rule, and the ``data`` axis is untouched (no in_spec names
+    it, so inputs and output are replicated over it by construction)."""
     mesh = _serve_tp_mesh(q.shape[1], k.shape[1])
     if mesh is None:
-        return segment_attention_op(q, k, v, q_pos, k_pos, q_seg, k_seg,
-                                    window=window)
+        return op(q, k, v, *index)
 
-    def body(q_l, k_l, v_l, qp, kp, qs, ks):
-        o = segment_attention_op(q_l, k_l, v_l, qp, kp, qs, ks,
-                                 window=window)
-        return jax.lax.all_gather(o, "model", axis=1, tiled=True)
+    def heads(a):
+        return P(None, "model", *([None] * (a.ndim - 2)))
 
-    return shard_map(
+    def body(*args):
+        return jax.lax.all_gather(op(*args), "model", axis=1, tiled=True)
+
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, "model", None), P(None, "model", None),
-                  P(None, "model", None), P(None), P(None), P(None),
-                  P(None)),
-        out_specs=P(None, None, None),
-        check_rep=False)(q, k, v, q_pos, k_pos, q_seg, k_seg)
+        in_specs=(heads(q), heads(k), heads(v)) + (P(),) * len(index),
+        out_specs=P(), check_vma=False)(q, k, v, *index)
+
+
+def tp_segment_attention(q, k, v, q_pos, k_pos, q_seg, k_seg, *,
+                         window: int = 0):
+    """Head-sharded flat segment attention: q [P,H,D]; k,v [N,Kv,D]."""
+    from repro.kernels.segment_attention import segment_attention_op
+    return _head_parallel(partial(segment_attention_op, window=window),
+                          q, k, v, q_pos, k_pos, q_seg, k_seg)
 
 
 def tp_paged_segment_attention(q, k_store, v_store, block_tables, q_pos,
@@ -138,26 +140,22 @@ def tp_paged_segment_attention(q, k_store, v_store, block_tables, q_pos,
 
     The block stores shard on the ``Kv`` head dim (axis 1) — the same
     placement the engine pins on the cache arrays, so the gather through
-    the block table stays shard-local.  Block *indices* (tables, positions,
-    segments) are global and replicated."""
+    the block table stays shard-local."""
     from repro.kernels.segment_attention import paged_segment_attention_op
-    mesh = _serve_tp_mesh(q.shape[1], k_store.shape[1])
-    if mesh is None:
-        return paged_segment_attention_op(q, k_store, v_store, block_tables,
-                                          q_pos, q_seg, window=window)
+    return _head_parallel(partial(paged_segment_attention_op, window=window),
+                          q, k_store, v_store, block_tables, q_pos, q_seg)
 
-    def body(q_l, k_l, v_l, bt, qp, qs):
-        o = paged_segment_attention_op(q_l, k_l, v_l, bt, qp, qs,
-                                       window=window)
-        return jax.lax.all_gather(o, "model", axis=1, tiled=True)
 
-    return shard_map(
-        body, mesh=mesh,
-        in_specs=(P(None, "model", None), P(None, "model", None, None),
-                  P(None, "model", None, None), P(None, None), P(None),
-                  P(None)),
-        out_specs=P(None, None, None),
-        check_rep=False)(q, k_store, v_store, block_tables, q_pos, q_seg)
+def tp_paged_decode_attention(q, k_store, v_store, block_tables, q_pos, *,
+                              window: int = 0):
+    """Head-sharded paged decode attention: q [B,H,D]; stores [N,Kv,T,D];
+    q_pos [B].  The decode-only tick needs it as much as the packed tick
+    needs :func:`tp_paged_segment_attention`: the compiler cannot partition
+    a Mosaic kernel, so a sharded store reaches one only through
+    ``shard_map``."""
+    from repro.kernels.paged_attention import paged_decode_attention_op
+    return _head_parallel(partial(paged_decode_attention_op, window=window),
+                          q, k_store, v_store, block_tables, q_pos)
 
 
 def sp_decode_combine(o: jax.Array, m: jax.Array, l: jax.Array,

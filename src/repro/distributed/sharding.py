@@ -16,17 +16,10 @@ import threading
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental around 0.4.35/0.5; support both so
-# multi-device paths (EP MoE, coordinated controllers) run on either version.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map
-
 __all__ = [
     "AxisRules", "DEFAULT_RULES", "SERVE_TP_RULES", "use_mesh",
     "current_mesh", "logical_spec", "shard", "params_pspecs",
-    "named_sharding", "FSDP_THRESHOLD", "Axes", "A", "shard_map",
+    "named_sharding", "FSDP_THRESHOLD", "Axes", "A",
 ]
 
 

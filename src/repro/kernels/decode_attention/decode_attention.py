@@ -38,7 +38,7 @@ def padded_cache_len(n: int, block_kv: int = DEFAULT_BLOCK_KV) -> int:
     return -(-n // block_kv) * block_kv
 
 
-def _kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
+def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
             m_scr, l_scr, acc_scr, *, window: int, block_kv: int):
     ki = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -52,8 +52,8 @@ def _kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)           # [G, d]
     k = k_ref[0, 0].astype(jnp.float32)           # [bkv, d]
     v = v_ref[0, 0].astype(jnp.float32)
-    k_pos = kpos_ref[0]                           # [bkv]
-    q_pos = qpos_ref[0]                           # scalar int32
+    k_pos = kpos_ref[0]                           # [1, bkv]
+    q_pos = qpos_ref[pl.program_id(0)]            # scalar int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -62,12 +62,12 @@ def _kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     valid = (k_pos >= 0) & (k_pos <= q_pos)
     if window > 0:
         valid &= (q_pos - k_pos) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[:, 0]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.where(valid[None, :], jnp.exp(s - m_cur[:, None]), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_cur[:, None]), 0.0)
     l_cur = alpha * l_scr[:, 0] + jnp.sum(p, axis=1)
     acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -98,26 +98,34 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)), constant_values=-1)
     sp = s + pad
     qg = q.reshape(b, kv_heads, g, d)
-    q_pos = q_pos.astype(jnp.int32).reshape(b, 1)
+    # k_pos as [B, 1, S]: the (1, block_kv) trailing block then satisfies the
+    # TPU tiling rule; q_pos is a per-row scalar, read from SMEM
+    k_pos = k_pos.astype(jnp.int32)[:, None, :]
+    q_pos = q_pos.astype(jnp.int32)
 
-    grid = (b, kv_heads, sp // block_kv)
-    out = pl.pallas_call(
-        functools.partial(_kernel, window=window, block_kv=block_kv),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, kv_heads, sp // block_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, h_, ki: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, ki: (b_, h_, ki, 0)),
-            pl.BlockSpec((1, 1, block_kv, d), lambda b_, h_, ki: (b_, h_, ki, 0)),
-            pl.BlockSpec((1, block_kv), lambda b_, h_, ki: (b_, ki)),
-            pl.BlockSpec((1, 1), lambda b_, h_, ki: (b_, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda b_, h_, ki, qp: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda b_, h_, ki, qp: (b_, h_, ki, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda b_, h_, ki, qp: (b_, h_, ki, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b_, h_, ki, qp: (b_, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h_, ki: (b_, h_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv_heads, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, d),
+                               lambda b_, h_, ki, qp: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, window=window, block_kv=block_kv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv_heads, g, d), q.dtype),
         interpret=interpret,
-    )(qg, k, v, k_pos, q_pos)
+    )(q_pos, qg, k, v, k_pos)
     return out.reshape(b, h, d)

@@ -15,10 +15,10 @@ Layout / ABI (shared with ``repro.serve.paging`` and ``models.blocks``):
 
 Grid = (batch, kv_heads, max_blocks_per_seq) with the block-table axis
 innermost/sequential; the (m, l, acc) online-softmax state lives in VMEM
-scratch exactly as in ``decode_attention``.  The block table is a
-scalar-prefetch operand, so each K/V block's DMA is issued from
-``block_tables[b, i]`` *before* the kernel body runs — the gather is free,
-no dense [B, S] cache is ever materialized.  Invalid table entries (-1) are
+scratch exactly as in ``decode_attention``.  The block table and the
+per-row query positions are scalar-prefetch operands (SMEM), so each K/V
+block's DMA is issued from ``block_tables[b, i]`` *before* the kernel body
+runs — the gather is free, no dense [B, S] cache is ever materialized.  Invalid table entries (-1) are
 clamped to block 0 for the DMA and fully masked in the body.
 
 Unlike the dense kernel there is no ``k_pos`` operand: positions are
@@ -38,7 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(bt_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
+def _kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, window: int, block_tokens: int):
     b = pl.program_id(0)
     i = pl.program_id(2)
@@ -54,7 +54,7 @@ def _kernel(bt_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
     k = k_ref[0, 0].astype(jnp.float32)              # [T, d]
     v = v_ref[0, 0].astype(jnp.float32)
     entry = bt_ref[b, i]                             # scalar int32
-    q_pos = qpos_ref[0, 0]                           # scalar int32
+    q_pos = qpos_ref[b]                              # scalar int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -96,25 +96,25 @@ def paged_decode_attention(q: jax.Array, k_store: jax.Array,
     m = block_tables.shape[1]
     g = h // kv_heads
     qg = q.reshape(b, kv_heads, g, d)
-    q_pos = q_pos.astype(jnp.int32).reshape(b, 1)
+    q_pos = q_pos.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def kv_map(b_, h_, i_, bt):
+    def kv_map(b_, h_, i_, bt, qp):
         # -1 entries are clamped to a real block for the DMA; the body
         # masks them out entirely via `entry >= 0`
         return (jnp.clip(bt[b_, i_], 0, n_blocks - 1), h_, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, kv_heads, m),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, h_, i_, bt: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, g, d),
+                         lambda b_, h_, i_, bt, qp: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, t, d), kv_map),
             pl.BlockSpec((1, 1, t, d), kv_map),
-            pl.BlockSpec((1, 1), lambda b_, h_, i_, bt: (b_, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h_, i_, bt: (b_, h_, 0, 0)),
+                               lambda b_, h_, i_, bt, qp: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, 128), jnp.float32),
@@ -126,5 +126,5 @@ def paged_decode_attention(q: jax.Array, k_store: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g, d), q.dtype),
         interpret=interpret,
-    )(block_tables, qg, k_store, v_store, q_pos)
+    )(block_tables, q_pos, qg, k_store, v_store)
     return out.reshape(b, h, d)

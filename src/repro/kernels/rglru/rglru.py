@@ -28,28 +28,36 @@ CHUNK = 128
 BLOCK_F = 512
 
 
+def _slab_rows(chunk: int) -> int:
+    return next((r for r in (16, 8) if chunk % r == 0), chunk)
+
+
 def _kernel(loga_ref, b_ref, h0_ref, h_ref, hout_ref, h_scr, *, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        h_scr[...] = h0_ref[:].astype(jnp.float32)
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    log_a = loga_ref[0].astype(jnp.float32)    # [L, F]
-    b = b_ref[0].astype(jnp.float32)           # [L, F]
+    # the chunk is walked in slabs of `slab` rows at aligned offsets: each
+    # slab is loaded once, its rows stepped in order, and the results
+    # gathered into one slab store (Mosaic refuses unaligned row access)
+    slab = _slab_rows(chunk)
+    rid = jax.lax.broadcasted_iota(jnp.int32, (slab, h_scr.shape[1]), 0)
 
-    def step(t, carry):
-        h, out = carry
-        h = jnp.exp(log_a[t]) * h + b[t]
-        out = jax.lax.dynamic_update_index_in_dim(out, h, t, 0)
-        return h, out
+    def step(si, h):                           # h: [1, F]
+        rows = pl.ds(pl.multiple_of(si * slab, slab), slab)
+        a = jnp.exp(loga_ref[0, rows, :].astype(jnp.float32))   # [slab, F]
+        x = b_ref[0, rows, :].astype(jnp.float32)
+        out = jnp.zeros_like(x)
+        for t in range(slab):
+            h = a[t:t + 1] * h + x[t:t + 1]
+            out = jnp.where(rid == t, h, out)
+        h_ref[0, rows, :] = out.astype(h_ref.dtype)
+        return h
 
-    h0 = h_scr[0]
-    out0 = jnp.zeros_like(b)
-    h_fin, out = jax.lax.fori_loop(0, chunk, step, (h0, out0))
-    h_scr[...] = h_fin[None, :]
-    h_ref[0] = out.astype(h_ref.dtype)
-    hout_ref[...] = h_scr[...]
+    h_scr[...] = jax.lax.fori_loop(0, chunk // slab, step, h_scr[...])
+    hout_ref[0] = h_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_f", "interpret"))
@@ -59,6 +67,8 @@ def rglru_scan_state(log_a: jax.Array, b: jax.Array, h0: jax.Array, *,
     """log_a, b: [B, S, F]; h0: [B, F] f32 carried state.
     Returns (h [B, S, F], h_out [B, F] f32)."""
     bsz, s, f = log_a.shape
+    # state as [B, 1, F] so its (1, block_f) trailing block is a legal tile
+    h0 = h0.astype(jnp.float32)[:, None, :]
     chunk = min(chunk, s)
     block_f = min(block_f, f)
     assert s % chunk == 0 and f % block_f == 0
@@ -69,21 +79,21 @@ def rglru_scan_state(log_a: jax.Array, b: jax.Array, h0: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1, chunk, block_f), lambda b_, fi, ci: (b_, ci, fi)),
             pl.BlockSpec((1, chunk, block_f), lambda b_, fi, ci: (b_, ci, fi)),
-            pl.BlockSpec((1, block_f), lambda b_, fi, ci: (b_, fi)),
+            pl.BlockSpec((1, 1, block_f), lambda b_, fi, ci: (b_, 0, fi)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_f),
                          lambda b_, fi, ci: (b_, ci, fi)),
-            pl.BlockSpec((1, block_f), lambda b_, fi, ci: (b_, fi)),
+            pl.BlockSpec((1, 1, block_f), lambda b_, fi, ci: (b_, 0, fi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, s, f), b.dtype),
-            jax.ShapeDtypeStruct((bsz, f), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, 1, f), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_f), jnp.float32)],
         interpret=interpret,
-    )(log_a, b, h0.astype(jnp.float32))
-    return h, h_out
+    )(log_a, b, h0)
+    return h, h_out[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_f", "interpret"))

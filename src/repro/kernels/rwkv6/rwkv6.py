@@ -40,6 +40,7 @@ CHUNK = 32
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
             s_scr, *, chunk: int):
     ci = pl.program_id(1)
+    n = s_scr.shape[0]
 
     @pl.when(ci == 0)
     def _init():
@@ -51,20 +52,32 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     lw = w_ref[0].astype(jnp.float32)      # log decay, [L, N]
     u = u_ref[0].astype(jnp.float32)       # [1, N] bonus
 
-    cum = jnp.cumsum(lw, axis=0)           # [L, N] inclusive: sum_{u<=t} lw_u
+    # prefix sums as matmuls with constant masks (Mosaic has no cumsum):
+    # cum[t] = sum_{u<=t} lw_u, inclusive; tot[i, j] = sum_u lw_u[i], the
+    # chunk's total log decay of state row i, broadcast over columns j
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    hi = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general((row >= col).astype(jnp.float32), lw,
+                              (((1,), (0,)), ((), ())), precision=hi,
+                              preferred_element_type=jnp.float32)  # [L, N]
+    tot = jax.lax.dot_general(lw, jnp.ones((chunk, n), jnp.float32),
+                              (((0,), (0,)), ((), ())), precision=hi,
+                              preferred_element_type=jnp.float32)  # [N, N]
+    cum_last = jnp.sum(lw, axis=0, keepdims=True)                  # [1, N]
     ecum = cum - lw                        # exclusive: sum_{u<t} lw_u
     A = jnp.exp(ecum)                      # decay applied to the r-side read
-    A_total = jnp.exp(cum[-1])             # [N]
 
-    # D[t, s, :] = prod_{s<u<t} w_u = exp(ecum_t - cum_s), strictly lower
-    ct = ecum[:, None, :]
-    cs = cum[None, :, :]
-    strict = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    D = jnp.where(strict[:, :, None], jnp.exp(ct - cs), 0.0)   # [L, L, N]
-
-    # y_intra[t, j] = sum_s sum_i r[t,i] D[t,s,i] k[s,i] v[s,j]
-    scores = jnp.einsum("ti,tsi,si->ts", r, D, k)              # [L, L]
+    # scores[t, s] = sum_i r[t,i] k[s,i] prod_{s<u<t} w_u[i]
+    #              = sum_i r[t,i] k[s,i] exp(ecum[t,i] - cum[s,i]), t > s,
+    # built one key column at a time from 2-D tiles.  The exponent is <= 0
+    # wherever t > s; clamping it keeps the masked entries finite.
+    scores = jnp.zeros((chunk, chunk), jnp.float32)
+    for j in range(chunk):
+        decay = jnp.exp(jnp.minimum(ecum - cum[j:j + 1], 0.0))    # [L, N]
+        col_j = jnp.sum(r * decay * k[j:j + 1], axis=1, keepdims=True)
+        scores = jnp.where(col == j, col_j, scores)
+    scores = jnp.where(row > col, scores, 0.0)                    # [L, L]
     y_intra = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     y_diag = jnp.sum(r * u * k, axis=1, keepdims=True) * v     # [L, N]
@@ -72,8 +85,8 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
-    decay_k = jnp.exp(cum[-1][None, :] - cum) * k              # [L, N]
-    s_scr[...] = A_total[:, None] * s_scr[...] + jax.lax.dot_general(
+    decay_k = jnp.exp(cum_last - cum) * k                      # [L, N]
+    s_scr[...] = jnp.exp(tot) * s_scr[...] + jax.lax.dot_general(
         decay_k, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 

@@ -25,6 +25,7 @@ import json
 
 from repro.core import ControllerModel, GoalSpec, SmartConfIndirect
 from repro.core.smartconf import ConfRegistry
+from repro.launch.runtime import enable_compile_cache
 
 
 def measure(arch: str, shape_name: str, n_micro: int) -> dict:
@@ -124,6 +125,7 @@ def main() -> None:
     ap.add_argument("--budget-gb", type=float, default=64.0)
     ap.add_argument("--out", default="experiments/autotune_microbatch.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     history = autotune(args.arch, args.shape, args.budget_gb * 1e9)
     for rec in history:

@@ -29,6 +29,7 @@ import jax
 from repro.configs import ARCH_IDS, SHAPES, cells, get_config
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
+from repro.launch.runtime import enable_compile_cache
 from repro.models import zoo
 from repro.optim import adamw
 from repro.roofline import analysis as roof
@@ -214,6 +215,7 @@ def main() -> None:
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--out-dir", default=OUT_DIR)
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
     targets = []

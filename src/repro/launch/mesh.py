@@ -9,6 +9,7 @@ is the slow (DCN/ICI-bridge) dimension and carries only data parallelism.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh"]
 
@@ -16,7 +17,8 @@ __all__ = ["make_production_mesh", "make_host_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
@@ -49,4 +51,5 @@ def make_host_mesh(*, data: int | None = None, model: int = 1):
             f"devices but only {n} are visible; run under XLA_FLAGS="
             f"--xla_force_host_platform_device_count={data * model} or "
             f"shrink the mesh")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -1,8 +1,11 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Runs the continuous-batching engine with SmartConf-governed admission and
-KV budgets against a synthetic request trace (reduced config on CPU; full
-configs deploy the dry-run-validated shardings on real meshes).
+KV budgets against a synthetic request trace: a reduced config by default,
+the published widths with ``--full-size``.  The HBM budget is the device's
+own memory limit less an activation margin where the device reports one
+(a TPU), and the weights plus ``--budget-headroom-mb`` where it does not
+(the CPU backend).
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import reduced
+from repro.launch.runtime import device_hbm_budget, enable_compile_cache
 from repro.models import zoo
 from repro.serve import Request, ServeEngine, ServeOptions
 
@@ -26,7 +30,9 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=128)
-    ap.add_argument("--budget-headroom-mb", type=float, default=2.0)
+    ap.add_argument("--budget-headroom-mb", type=float, default=2.0,
+                    help="HBM budget above the weights on a device that "
+                         "reports no memory limit (the CPU backend)")
     ap.add_argument("--prefill-mode", default="auto",
                     choices=["auto", "bucketed", "packed", "one_shot"],
                     help="packed = unified ticks: ONE token-packed ragged "
@@ -120,13 +126,16 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_size:
         cfg = reduced(cfg)
-    params, _ = zoo.init(cfg, jax.random.key(0))
-    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                  for x in jax.tree.leaves(params))
-    budget = int(weights + args.budget_headroom_mb * 1e6)
+    params = zoo.init_params(cfg, jax.random.key(0))
+    budget = device_hbm_budget()
+    if budget is None:
+        weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree.leaves(params))
+        budget = int(weights + args.budget_headroom_mb * 1e6)
     if args.replicas > 1 and args.trace is None:
         raise SystemExit("--replicas N needs --trace: the ReplicaRouter "
                          "serves an open-loop arrival stream")

@@ -11,6 +11,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import reduced
+from repro.launch.runtime import enable_compile_cache
 from repro.optim import adamw
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -21,13 +22,14 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--workdir", default="/tmp/repro_launch_train")
+    ap.add_argument("--workdir", default="experiments/train")
     ap.add_argument("--full-size", action="store_true",
                     help="use the full architecture (TPU-scale memory!)")
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_size:
         cfg = reduced(cfg)

@@ -651,8 +651,9 @@ def block_apply_step(cfg, kind: str, params: dict, x: jax.Array,
             ok = jnp.ones(pos.shape, bool) if active is None else active
             new_cache = _paged_scatter(cache, k_t, v_t, pos[:, None],
                                        ok[:, None], block_tables)
-            from repro.kernels.paged_attention import paged_decode_attention_op
-            o = paged_decode_attention_op(q[:, 0], new_cache["k"],
+            from repro.distributed.collectives import (
+                tp_paged_decode_attention)
+            o = tp_paged_decode_attention(q[:, 0], new_cache["k"],
                                           new_cache["v"], block_tables, pos,
                                           window=window)
             x = x + layers.attn_output(params["attn"], o[:, None])

@@ -97,11 +97,14 @@ def attention_init(key, cfg, *, cross: bool = False) -> tuple[dict, dict]:
     hd = cfg.resolved_head_dim
     h, kv = cfg.num_heads, cfg.num_kv_heads
     ks = jax.random.split(key, 4)
+    # scaled by each projection's true fan-in (d in, h*hd out): scaling by a
+    # head dim instead makes the scores hundreds wide, the softmax one-hot,
+    # and every rounding difference a different attended key
     params = {
-        "wq": _dense_init(ks[0], (d, h, hd), cfg.dtype),
-        "wk": _dense_init(ks[1], (d, kv, hd), cfg.dtype),
-        "wv": _dense_init(ks[2], (d, kv, hd), cfg.dtype),
-        "wo": _dense_init(ks[3], (h, hd, d), cfg.dtype),
+        "wq": _dense_init(ks[0], (d, h, hd), cfg.dtype, in_axis=0),
+        "wk": _dense_init(ks[1], (d, kv, hd), cfg.dtype, in_axis=0),
+        "wv": _dense_init(ks[2], (d, kv, hd), cfg.dtype, in_axis=0),
+        "wo": _dense_init(ks[3], (h * hd, d), cfg.dtype).reshape(h, hd, d),
     }
     axes = {
         "wq": A("embed", "heads", None),

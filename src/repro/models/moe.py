@@ -273,7 +273,7 @@ def moe_apply_ep(params: dict, x: jax.Array, cfg, *, return_aux: bool = False,
     no mesh with a 'model' axis is active or experts don't divide it.
     ``valid`` masks pad tokens out of the capacity count (chunked prefill)."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import current_mesh, shard_map
+    from repro.distributed.sharding import current_mesh
 
     mesh = current_mesh()
     if (mesh is None or "model" not in mesh.axis_names
@@ -292,7 +292,7 @@ def moe_apply_ep(params: dict, x: jax.Array, cfg, *, return_aux: bool = False,
 
     if valid is None:
         valid = jnp.ones(x.shape[:2], bool)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_moe_local, cfg=cfg, e_local=e_local, axis_name="model"),
         mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
@@ -404,7 +404,7 @@ def moe_apply_ep_serve(params: dict, x: jax.Array, cfg,
     ``valid`` ([B, S] bool) masks inactive decode slots out of the capacity
     count so a free slot's stale token can't steal an expert slot."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import current_mesh, shard_map
+    from repro.distributed.sharding import current_mesh
 
     mesh = current_mesh()
     dp_axes = tuple(a for a in ("pod", "data") if a in (mesh.axis_names if mesh else ()))
@@ -419,7 +419,7 @@ def moe_apply_ep_serve(params: dict, x: jax.Array, cfg,
 
     if valid is None:
         valid = jnp.ones(x.shape[:2], bool)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_moe_local_serve, cfg=cfg, e_local=e_local, dp_axes=dp_axes),
         mesh=mesh,
         in_specs=(P(), P("model", None, dspec), P("model", None, dspec),

@@ -8,6 +8,7 @@ and the pattern remainder are unrolled explicitly.
 
 Public entry points (all pure):
     init(cfg, key)                      -> (params, axes)
+    init_params(cfg, key)               -> params  [one jitted program]
     forward(cfg, params, batch)         -> logits | hidden
     loss_fn(cfg, params, batch)         -> (loss, aux)     [chunked CE]
     prefill(cfg, params, batch, cache_len) -> (last_logits, caches)
@@ -100,20 +101,29 @@ def init(cfg, key) -> tuple[dict, dict]:
             is_leaf=lambda x: isinstance(x, Axes))
 
     def stack_init(kinds, key, n_copies=1, *, stack=False):
-        ps, axs = [], None
-        for i in range(n_copies):
+        keys = []                                   # [n_copies][len(kinds)]
+        for _ in range(n_copies):
             kp, key = jax.random.split(key)
-            group_p, group_a = [], []
-            for j, kind in enumerate(kinds):
+            row = []
+            for _ in kinds:
                 kj, kp = jax.random.split(kp)
-                p, a = B.block_init(kj, cfg, kind)
-                group_p.append(p)
-                group_a.append(a)
-            ps.append(group_p)
-            axs = group_a
+                row.append(kj)
+            keys.append(row)
+        axs = []
+
+        def group_init(row):
+            out = [B.block_init(kj, cfg, kind) for kj, kind in zip(row, kinds)]
+            axs[:] = [a for _, a in out]
+            return [p for p, _ in out]
+
         if not stack:
-            return ps[0], axs
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
+            return group_init(keys[0]), axs
+        # each copy is written straight into the stacked arrays, so one
+        # group's worth of temporaries is live at a time, never two copies
+        # of the whole stack
+        _, stacked = jax.lax.scan(
+            lambda _, row: (None, group_init(list(row))), None,
+            jnp.stack([jnp.stack(row) for row in keys]))
         return stacked, axs
 
     if prefix:
@@ -147,6 +157,15 @@ def init(cfg, key) -> tuple[dict, dict]:
         params["cross"] = jax.tree.map(lambda *xs: jnp.stack(xs), *xp)
         axes["cross"] = stack_axes(xa)
     return params, axes
+
+
+def init_params(cfg, key, *, sharding=None) -> dict:
+    """:func:`init`'s params, built on device by one jitted program.
+
+    ``sharding`` (optional) places every leaf as it is built: replicated
+    over a serving mesh, each device makes its own copy, and no device
+    ever holds a second one in transit."""
+    return jax.jit(lambda k: init(cfg, k)[0], out_shardings=sharding)(key)
 
 
 # ---------------------------------------------------------------------------

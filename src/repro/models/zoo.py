@@ -9,7 +9,7 @@ import numpy as np
 from repro.configs.base import ArchConfig, ShapeConfig
 from . import transformer
 
-__all__ = ["init", "loss_fn", "forward", "prefill", "prefill_chunk",
+__all__ = ["init", "init_params", "loss_fn", "forward", "prefill", "prefill_chunk",
            "prefill_packed", "step_packed", "step_spec",
            "supports_chunked_prefill",
            "supports_paged_kv", "decode_step", "init_cache",
@@ -17,6 +17,7 @@ __all__ = ["init", "loss_fn", "forward", "prefill", "prefill_chunk",
            "make_batch", "input_specs"]
 
 init = transformer.init
+init_params = transformer.init_params
 loss_fn = transformer.loss_fn
 forward = transformer.forward
 prefill = transformer.prefill

@@ -112,6 +112,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ArchConfig
 from repro.core import (ControllerModel, GoalSpec, Guardrails, HBMAccountant,
@@ -380,6 +381,12 @@ class ServeEngine:
                 env_forced=opts.mesh_env_forced)
         self.tp_shards = (int(self.mesh.shape["model"])
                           if self.mesh is not None else 1)
+        if self.mesh is not None:
+            # every device of the mesh holds the whole weight tree (only
+            # attention heads shard, inside the tick); a tree that is
+            # already placed so is not copied
+            self.params = jax.device_put(
+                params, NamedSharding(self.mesh, PartitionSpec()))
 
         self.accountant = HBMAccountant(budget_bytes=hbm_budget_bytes)
         weight_bytes = sum(np.prod(x.shape) * x.dtype.itemsize

@@ -37,6 +37,34 @@ def test_smoke_train_step(arch_id, rng):
 
 
 @pytest.mark.parametrize("arch_id", ARCH_IDS)
+def test_init_params_matches_init(arch_id):
+    """The one-program device init builds exactly init's params."""
+    cfg = _smoke_cfg(arch_id)
+    want, _ = zoo.init(cfg, jax.random.key(3))
+    got = zoo.init_params(cfg, jax.random.key(3))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def test_attention_init_scales_by_fan_in():
+    """Projections are scaled by their true fan-in, so random-weight
+    attention scores stay O(1) and the softmax is not one-hot."""
+    cfg = dataclasses.replace(get_config("yi-6b"), num_layers=1,
+                              d_model=512, num_heads=4, num_kv_heads=2,
+                              d_ff=1024, vocab_size=256)
+    params = zoo.init_params(cfg, jax.random.key(0))
+    attn = jax.tree.map(lambda t: t[0], params["groups"][0]["attn"])
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    for name, fan_in in (("wq", d), ("wk", d), ("wv", d),
+                         ("wo", cfg.num_heads * hd)):
+        bound = float(jnp.max(jnp.abs(attn[name].astype(jnp.float32))))
+        assert 0.9 / np.sqrt(fan_in) < bound <= 1.0 / np.sqrt(fan_in) + 1e-3
+
+
+@pytest.mark.parametrize("arch_id", ARCH_IDS)
 def test_smoke_forward_shapes(arch_id, rng):
     cfg = _smoke_cfg(arch_id)
     params, _ = zoo.init(cfg, jax.random.key(0))

@@ -17,15 +17,20 @@ sys.path.insert(0, "SRCPATH")
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map  # version-compat alias
+
+def auto_mesh(shape, names, devices=None):
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
+
 
 assert len(jax.devices()) == 8
 
 # ---- 1. compressed gradient all-reduce over a mesh axis -------------------
 from repro.distributed.collectives import compressed_psum_grads
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 grads = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8) / 7.0}
 
 def f(g):
@@ -56,7 +61,7 @@ qpos = jnp.full((B,), S - 1, jnp.int32)
 full = decode_attention_ref(q, k, v, kpos, qpos)
 
 from repro.distributed.collectives import sp_decode_combine
-mesh2 = jax.make_mesh((8,), ("model",))
+mesh2 = auto_mesh((8,), ("model",))
 
 def sp_decode(q, k, v, kpos, qpos):
     # each shard sees S/8 of the cache; partial (o, m, l) then combine
@@ -103,11 +108,11 @@ cfg = reduced(get_config("yi-6b"))
 cfg = dataclasses.replace(cfg, d_model=64, num_heads=4, num_kv_heads=2,
                           vocab_size=512)
 shape = ShapeConfig("mini", 64, 8, "train")
-mesh_s = jax.make_mesh((2, 2), ("data", "model"))
+mesh_s = auto_mesh((2, 2), ("data", "model"))
 lowered, _, _ = lower_cell("yi-6b", "mini", multi_pod=False, mesh=mesh_s,
                            shape=shape, cfg=cfg)
 lowered.compile()
-mesh_m = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_m = auto_mesh((2, 2, 2), ("pod", "data", "model"))
 lowered, _, _ = lower_cell("yi-6b", "mini", multi_pod=True, mesh=mesh_m,
                            shape=shape, cfg=cfg)
 compiled = lowered.compile()
@@ -126,7 +131,7 @@ with tempfile.TemporaryDirectory() as td:
     x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))   # 8-way
     save(td, 1, {"x": xs})
-    mesh_b = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
+    mesh_b = auto_mesh((2,), ("data",), devices=jax.devices()[:2])
     tgt = NamedSharding(mesh_b, P("data", None))                   # 2-way
     got, _, _ = restore(td, None, {"x": jax.ShapeDtypeStruct(x.shape, x.dtype)},
                         shardings={"x": tgt})
