@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - busy / window, busy being the union of the operations' intervals,
+averaged over the chips used."""
+
+
+def read(run):
+    red = run.reduction
+    if red is None or red.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - red.busy_s / red.window_s)
