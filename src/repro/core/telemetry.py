@@ -10,9 +10,10 @@ cooperating pieces, bundled behind one :class:`Telemetry` hub:
 - :class:`Tracer` — span tracer emitting Chrome trace-event JSON
   (``trace.json``), loadable in Perfetto / ``chrome://tracing``.  The
   serve engine stamps one span per tick with nested phase spans
-  (control → admit → schedule → pack → dispatch → sample → finish),
-  chaos events and preemptions as instant markers, and request
-  lifetimes as async begin/end pairs.
+  (control → admit → schedule → pack → dispatch → wait → sample →
+  finish), each timed from the clock at its entry and exit, chaos
+  events and preemptions as instant markers, and request lifetimes as
+  async begin/end pairs.
 - :class:`FlightRecorder` — bounded ring of the last N ticks of raw
   sensor readings (pre- and post-``sensor_tap``), dumped automatically
   on guardrail faults, rejection storms, or chaos triggers.
@@ -37,6 +38,7 @@ Design constraints, both load-bearing:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -293,37 +295,19 @@ class Tracer:
             ev["args"] = args
         self._emit(ev)
 
-    # --- tick-structured spans -------------------------------------------
-    # The engine brackets each tick with begin_tick()/end_tick() and calls
-    # phase() at each internal stage boundary.  Under a frozen-per-tick
-    # VirtualClock every phase would collapse to zero duration, so phases
-    # are synthesized as equal slices of the tick span: the *ordering*
-    # admit -> pack -> dispatch -> ... is the ground truth being traced,
-    # not wall sub-timings.
-
-    def begin_tick(self, tick: int) -> None:
-        self._tick_no = tick
-        self._tick_ts = self.now_us()
-        self._phases: list[str] = []
-
-    def phase(self, name: str) -> None:
-        self._phases.append(name)
-
-    def end_tick(self, args: dict[str, Any] | None = None) -> None:
-        ts0 = self._tick_ts
-        end = self.now_us()
-        dur = max(end - ts0, len(self._phases) or 1)
-        self.complete(f"tick {self._tick_no}", ts0, dur,
-                      tid=self.TID_ENGINE, args=args)
-        if self._phases:
-            slice_us = dur // len(self._phases)
-            rem = dur - slice_us * len(self._phases)
-            t = ts0
-            for i, name in enumerate(self._phases):
-                d = slice_us + (rem if i == len(self._phases) - 1 else 0)
-                self.complete(name, t, d, tid=self.TID_ENGINE,
-                              args={"tick": self._tick_no})
-                t += d
+    @contextlib.contextmanager
+    def span(self, name: str, args: dict[str, Any] | None = None, *,
+             tid: int = TID_ENGINE):
+        """A complete event from the clock's readings at entry and exit,
+        written when the block exits (an exception included).  The block
+        may add args to the yielded dict.  Under a ``VirtualClock`` frozen
+        within a tick the span is zero-length; nesting and order remain."""
+        args = dict(args or {})
+        ts = self.now_us()
+        try:
+            yield args
+        finally:
+            self.complete(name, ts, self.now_us() - ts, tid=tid, args=args)
 
     def to_json(self) -> dict[str, Any]:
         return {"traceEvents": list(self.events),

@@ -127,5 +127,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(q_pos, qg, k, v, k_pos)
     return out.reshape(b, h, d)

@@ -126,5 +126,6 @@ def paged_decode_attention(q: jax.Array, k_store: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, g, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, q_pos, qg, k_store, v_store)
     return out.reshape(b, h, d)

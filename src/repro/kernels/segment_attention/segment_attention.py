@@ -153,6 +153,7 @@ def segment_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="segment_attention",
     )(qt, kt, vt, q_pos.astype(jnp.int32)[None], q_seg.astype(jnp.int32)[None],
       k_pos.astype(jnp.int32)[None], k_seg.astype(jnp.int32)[None])
     return jnp.swapaxes(out, 0, 1)[:p]
@@ -252,6 +253,7 @@ def paged_segment_attention(q: jax.Array, k_store: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, pp, d), q.dtype),
         interpret=interpret,
+        name="paged_segment_attention",
     )(block_tables, qt, k_store, v_store,
       q_pos.astype(jnp.int32)[None], q_seg.astype(jnp.int32)[None])
     return jnp.swapaxes(out, 0, 1)[:p]

@@ -102,6 +102,7 @@ matrix leg), and ``one_shot`` is accepted as an alias for ``legacy``.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import enum
 import os
@@ -163,7 +164,14 @@ TICK_STATS_KEYS: tuple[str, ...] = (
     # tick dispatch (1 = single-device) — lets the router and the CI gates
     # tell a TP tick from a plain one without poking engine internals
     "tp_shards",
+    # appended (engine phase spans): seconds of the tick's serve.tick span
+    # outside its serve.wait and serve.fetch spans — the host's part
+    "host_s",
 )
+
+# phases whose span is the host waiting on the device: a tick's host_s
+# leaves them out
+_WAIT_PHASES = ("wait", "fetch")
 
 # rejections in one tick at or past this count dump the flight recorder:
 # a typed-rejection storm is exactly the "why did the engine shed all of
@@ -779,6 +787,7 @@ class ServeEngine:
         # REPRO_TELEMETRY=1 force-enables it for the CI telemetry leg
         # without touching call sites (same pattern as REPRO_PREFILL_MODE).
         self.ticks_run = 0
+        self._tick_wait_s = 0.0
         if telemetry is None and opts.telemetry_env:
             telemetry = Telemetry(enabled=True, clock=clock)
         self._tel = telemetry if (telemetry is not None
@@ -912,15 +921,57 @@ class ServeEngine:
 
     # ------------------------------------------------------------- one tick
     def tick(self) -> dict:
-        if self.mesh is None:
-            return self._tick_impl()
-        # the serving mesh + rule overlay are active for the WHOLE tick:
-        # every trace made this tick (step fns, COW copies, resizes) sees
-        # current_mesh(), so the attention wrappers engage shard_map and
-        # only head-parallel work shards (SERVE_TP_RULES nulls the
-        # training-only ff/vocab rules that would change contraction order)
-        with use_mesh(self.mesh, rules=SERVE_TP_RULES, fsdp=False):
-            return self._tick_impl()
+        t0 = self.clock()
+        self._tick_wait_s = 0.0
+        with self._span("tick") as note:
+            if self.mesh is None:
+                stats = self._tick_impl()
+            else:
+                # the serving mesh + rule overlay are active for the WHOLE
+                # tick: every trace made this tick (step fns, COW copies,
+                # resizes) sees current_mesh(), so the attention wrappers
+                # engage shard_map and only head-parallel work shards
+                # (SERVE_TP_RULES nulls the training-only ff/vocab rules
+                # that would change contraction order)
+                with use_mesh(self.mesh, rules=SERVE_TP_RULES, fsdp=False):
+                    stats = self._tick_impl()
+            note.update((k, stats[k]) for k in (
+                "tokens", "queued", "running", "rejected", "admit_tier_max",
+                "draining"))
+        stats["host_s"] = self.clock() - t0 - self._tick_wait_s
+        return stats
+
+    @contextlib.contextmanager
+    def _span(self, phase: str, **args):
+        """One phase of a tick, timed where it runs.  Always a
+        ``serve.<phase>`` span on the profiler's host plane, on the time
+        base of the device planes (``jax.profiler`` records it only while
+        a trace is capturing; otherwise the helper costs about 2.5 us).  The
+        tick itself is a step annotation numbered by ``ticks_run``.  With
+        telemetry on, also a complete event in ``trace.json`` (``tick N``,
+        or the bare phase name with ``tick`` and ``args``) from the
+        tracer's clock; the tick's body adds its args to the yielded dict.
+        ``wait`` and ``fetch`` spans add to the seconds that ``host_s``
+        leaves out."""
+        tick = self.ticks_run
+        if phase == "tick":
+            ann = jax.profiler.StepTraceAnnotation("serve.tick",
+                                                   step_num=tick)
+        else:
+            ann = jax.profiler.TraceAnnotation("serve." + phase, **args)
+        t0 = self.clock() if phase in _WAIT_PHASES else None
+        try:
+            with ann:
+                if self._tel is None:
+                    yield {}
+                else:
+                    event = ((f"tick {tick}", {}) if phase == "tick"
+                             else (phase, {"tick": tick, **args}))
+                    with self._tel.tracer.span(*event) as note:
+                        yield note
+        finally:
+            if t0 is not None:
+                self._tick_wait_s += self.clock() - t0
 
     def _tick_impl(self) -> dict:
         t0 = self.clock()
@@ -934,49 +985,40 @@ class ServeEngine:
         tel = self._tel
         if tel is not None:
             tel.audit.tick = self.ticks_run
-            tel.tracer.begin_tick(self.ticks_run)
             self._tick_readings = {}
             self._tick_rejects0 = self.rejected
         if self.preemption.triggered:
             # worker preemption: drain once (requeue every in-flight
             # request, copy-free), then idle — never crash mid-tick.  The
             # queue survives for a handoff or an in-place resume.
-            if not self._draining:
-                self._drain_for_preemption()
+            with self._span("drain"):
+                if not self._draining:
+                    self._drain_for_preemption()
             self.tick_latency.record(self.clock() - t0)
             stats = self._stats(0)
             self.ticks_run += 1
             if tel is not None:
-                tel.tracer.phase("drain")
                 self._tel_finish_tick(stats, self.clock() - t0)
             return stats
         self._draining = False          # preemption cleared: resume serving
-        if tel is not None:
-            tel.tracer.phase("control")
-        self._update_controllers()
-        self._shed_expired()
-        if tel is not None:
-            tel.tracer.phase("admit")
-        self._admit()
-        if tel is not None:
-            tel.tracer.phase("schedule")
-        self._schedule()
+        with self._span("control"):
+            self._update_controllers()
+            self._shed_expired()
+        with self._span("admit"):
+            self._admit()
+        with self._span("schedule"):
+            self._schedule()
         if self.spec_enabled:
             n_tokens = self._tick_spec()
         elif self.prefill_impl == "packed":
             n_tokens = self._tick_unified()
         else:
-            if tel is not None:
-                tel.tracer.phase("pack")
             self._prefill_tick()
-            if tel is not None:
-                tel.tracer.phase("dispatch")
             n_tokens = self._decode_tick()
-        if tel is not None:
-            tel.tracer.phase("finish")
-        self._finish()
-        if self._window_evict:
-            self._trim_windows()
+        with self._span("finish"):
+            self._finish()
+            if self._window_evict:
+                self._trim_windows()
         self.tick_latency.record(self.clock() - t0)
         stats = self._stats(n_tokens)
         self.ticks_run += 1
@@ -1041,6 +1083,9 @@ class ServeEngine:
             "decode_slots": self._tick_decode_slots,
             # mesh-serving sensor: model-axis shards behind this tick
             "tp_shards": self.tp_shards,
+            # the host's part of the tick: tick() sets it once the tick's
+            # span has closed
+            "host_s": 0.0,
         }
 
     def run(self, ticks: int) -> list[dict]:
@@ -1048,17 +1093,12 @@ class ServeEngine:
 
     # ----------------------------------------------------------- telemetry
     def _tel_finish_tick(self, stats: dict, wall_dt: float) -> None:
-        """Per-tick telemetry epilogue (only reached when enabled): close
-        the tick span, fold the stats into the metrics, snapshot the
-        sensor readings into the flight-recorder ring, and dump the ring
-        on any guardrail fault, fallback engagement, or rejection storm."""
+        """Per-tick telemetry epilogue (only reached when enabled): fold
+        the stats into the metrics, snapshot the sensor readings into the
+        flight-recorder ring, and dump the ring on any guardrail fault,
+        fallback engagement, or rejection storm."""
         tel = self._tel
         tick = stats["tick"]
-        tel.tracer.end_tick(args={
-            "tokens": stats["tokens"], "queued": stats["queued"],
-            "running": stats["running"], "rejected": stats["rejected"],
-            "admit_tier_max": stats["admit_tier_max"],
-            "draining": stats["draining"]})
         self._tel_c_ticks.inc()
         self._tel_c_tokens.inc(stats["tokens"])
         if wall_dt > 0.0:
@@ -1069,8 +1109,6 @@ class ServeEngine:
         m = tel.metrics
         m.gauge("serve.hbm_bytes").set(float(stats["hbm"]))
         m.gauge("serve.admit_tier_max").set(float(stats["admit_tier_max"]))
-        m.gauge("serve.kv_used_blocks").set(float(stats["kv_used_blocks"]))
-        m.gauge("serve.queued_tokens").set(float(self.queued_tokens))
         tel.flight.record(tick, dict(self._tick_readings))
         faults = 0
         for sc in (self.sc_queue, self.sc_kv, self.sc_chunk, self.sc_admit,
@@ -1613,73 +1651,71 @@ class ServeEngine:
         cost.  The unified stream owns every tick where prefill and decode
         overlap, which is where the split path paid its second dispatch."""
         if not self.prefilling:
-            if self._tel is not None:
-                self._tel.tracer.phase("dispatch")
             return self._decode_tick()
-        if self._tel is not None:
-            self._tel.tracer.phase("pack")
-        n_dec = len(self.running)
-        budget = max(1, min(int(self.prefill_chunk), self.packed_width))
-        demand = sum(len(r.prompt) - r.prefilled
-                     for r in self.prefilling.values())
-        pre_budget = min(max(1, budget - n_dec), demand)
-        # the engine's one documented width cap still applies: a saturated
-        # stream on a non-power-of-two cache_len must issue packed_width
-        # lanes, not the next power of two's permanently-dead padding
-        width = min(_bucket(pre_budget + n_dec), self.packed_width)
-        width = max(width, pre_budget + n_dec)   # never truncate the stream
-        tokens = np.zeros((1, width), np.int32)
-        slot_id = np.full((width,), -1, np.int32)
-        posw = np.zeros((width,), np.int32)
-        start = np.zeros((self.max_batch,), np.int32)
-        seg_len = np.zeros((self.max_batch,), np.int32)
-        is_dec = np.zeros((width,), bool)
-        sample = np.zeros((self.max_batch,), bool)
-        gidx = np.full((self.max_batch,), self.cache_len, np.int32)
-        done = np.zeros((self.max_batch,), bool)
-        cursor = 0
-        packed: list[tuple[int, Request, int]] = []
-        for slot, req in sorted(self.prefilling.items(),
-                                key=lambda sr: sr[1].admit_seq):
-            if cursor >= pre_budget:
-                break   # later arrivals re-pack from `prefilled` next tick
-            n = min(len(req.prompt) - req.prefilled, pre_budget - cursor)
-            tokens[0, cursor:cursor + n] = \
-                req.prompt[req.prefilled:req.prefilled + n]
-            slot_id[cursor:cursor + n] = slot
-            posw[cursor:cursor + n] = np.arange(req.prefilled,
-                                                req.prefilled + n)
-            start[slot] = req.prefilled
-            seg_len[slot] = n
-            if req.prefilled + n >= len(req.prompt):
-                done[slot] = sample[slot] = True
-                gidx[slot] = 0               # first token -> gen ring head
-            packed.append((slot, req, n))
-            cursor += n
-        pre_cursor = cursor
-        decoders: list[tuple[int, Request]] = []
-        for slot, req in sorted(self.running.items(),
-                                key=lambda sr: sr[1].admit_seq):
-            # the decode token itself lives on device (_slot_tok); the
-            # stream carries a placeholder the jitted step fills in
-            slot_id[cursor] = slot
-            posw[cursor] = int(self.slot_pos[slot])
-            is_dec[cursor] = True
-            start[slot] = int(self.slot_pos[slot])
-            seg_len[slot] = 1
-            sample[slot] = True
-            gidx[slot] = min(req.gen_count, self.cache_len)  # ==len => drop
-            decoders.append((slot, req))
-            cursor += 1
+        with self._span("pack"):
+            n_dec = len(self.running)
+            budget = max(1, min(int(self.prefill_chunk), self.packed_width))
+            demand = sum(len(r.prompt) - r.prefilled
+                         for r in self.prefilling.values())
+            pre_budget = min(max(1, budget - n_dec), demand)
+            # the engine's one documented width cap still applies: a saturated
+            # stream on a non-power-of-two cache_len must issue packed_width
+            # lanes, not the next power of two's permanently-dead padding
+            width = min(_bucket(pre_budget + n_dec), self.packed_width)
+            # never truncate the stream
+            width = max(width, pre_budget + n_dec)
+            tokens = np.zeros((1, width), np.int32)
+            slot_id = np.full((width,), -1, np.int32)
+            posw = np.zeros((width,), np.int32)
+            start = np.zeros((self.max_batch,), np.int32)
+            seg_len = np.zeros((self.max_batch,), np.int32)
+            is_dec = np.zeros((width,), bool)
+            sample = np.zeros((self.max_batch,), bool)
+            gidx = np.full((self.max_batch,), self.cache_len, np.int32)
+            done = np.zeros((self.max_batch,), bool)
+            cursor = 0
+            packed: list[tuple[int, Request, int]] = []
+            for slot, req in sorted(self.prefilling.items(),
+                                    key=lambda sr: sr[1].admit_seq):
+                if cursor >= pre_budget:
+                    break   # later arrivals re-pack from `prefilled` next tick
+                n = min(len(req.prompt) - req.prefilled, pre_budget - cursor)
+                tokens[0, cursor:cursor + n] = \
+                    req.prompt[req.prefilled:req.prefilled + n]
+                slot_id[cursor:cursor + n] = slot
+                posw[cursor:cursor + n] = np.arange(req.prefilled,
+                                                    req.prefilled + n)
+                start[slot] = req.prefilled
+                seg_len[slot] = n
+                if req.prefilled + n >= len(req.prompt):
+                    done[slot] = sample[slot] = True
+                    gidx[slot] = 0               # first token -> gen ring head
+                packed.append((slot, req, n))
+                cursor += n
+            pre_cursor = cursor
+            decoders: list[tuple[int, Request]] = []
+            for slot, req in sorted(self.running.items(),
+                                    key=lambda sr: sr[1].admit_seq):
+                # the decode token itself lives on device (_slot_tok); the
+                # stream carries a placeholder the jitted step fills in
+                slot_id[cursor] = slot
+                posw[cursor] = int(self.slot_pos[slot])
+                is_dec[cursor] = True
+                start[slot] = int(self.slot_pos[slot])
+                seg_len[slot] = 1
+                sample[slot] = True
+                # ==len => drop
+                gidx[slot] = min(req.gen_count, self.cache_len)
+                decoders.append((slot, req))
+                cursor += 1
         t_disp = self.clock()
-        if self._tel is not None:
-            self._tel.tracer.phase("dispatch")
-        self.caches, self._slot_tok, self._gen_buf = self._step_unified(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(slot_id), jnp.asarray(posw), jnp.asarray(start),
-            jnp.asarray(seg_len), jnp.asarray(is_dec), jnp.asarray(sample),
-            jnp.asarray(gidx), self._slot_tok, self._gen_buf,
-            self._bt() if self.paged else None)
+        with self._span("dispatch", program="unified", width=width):
+            self.caches, self._slot_tok, self._gen_buf = self._step_unified(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(slot_id), jnp.asarray(posw), jnp.asarray(start),
+                jnp.asarray(seg_len), jnp.asarray(is_dec),
+                jnp.asarray(sample), jnp.asarray(gidx), self._slot_tok,
+                self._gen_buf, self._bt() if self.paged else None)
         self.model_dispatches += 1
         self._tick_dispatches += 1
         self._prefill_shapes.add(width)        # O(1): one packed shape
@@ -1693,27 +1729,27 @@ class ServeEngine:
             # a sampled token is a completion boundary: wait for the device
             # (no host transfer) so TTFT/decode latency reflect compute,
             # not async dispatch depth
-            self._slot_tok.block_until_ready()
-        if self._tel is not None:
-            self._tel.tracer.phase("sample")
-        if n_dec:
-            dt = self.clock() - t_disp
-            self.decode_latency.record(dt)
-            if self._tel is not None and dt > 0.0:
-                self._tel_h_decode.record(dt)
-        now = self.clock()
-        for slot, req, n in packed:
-            req.prefilled += n
-            req.prefill_chunks += 1
-            if done[slot]:
-                req.gen_count = 1            # first token is on device
-                self._stamp_first_token(req, now)
-                self.slot_pos[slot] = len(req.prompt)
-                self.running[slot] = self.prefilling.pop(slot)
-                self._cache_insert(req)
-        for slot, req in decoders:
-            self.slot_pos[slot] += 1
-            req.gen_count += 1
+            with self._span("wait"):
+                self._slot_tok.block_until_ready()
+        with self._span("sample"):
+            if n_dec:
+                dt = self.clock() - t_disp
+                self.decode_latency.record(dt)
+                if self._tel is not None and dt > 0.0:
+                    self._tel_h_decode.record(dt)
+            now = self.clock()
+            for slot, req, n in packed:
+                req.prefilled += n
+                req.prefill_chunks += 1
+                if done[slot]:
+                    req.gen_count = 1            # first token is on device
+                    self._stamp_first_token(req, now)
+                    self.slot_pos[slot] = len(req.prompt)
+                    self.running[slot] = self.prefilling.pop(slot)
+                    self._cache_insert(req)
+            for slot, req in decoders:
+                self.slot_pos[slot] += 1
+                req.gen_count += 1
         self._tick_decode = n_dec
         self._tick_decode_slots = n_dec
         n_tokens = n_dec + int(done.sum())
@@ -1740,93 +1776,93 @@ class ServeEngine:
         never over-emit or outrun the KV lease."""
         if not self.prefilling and not self.running:
             return 0
-        if self._tel is not None:
-            self._tel.tracer.phase("pack")
-        L = self._spec_len_max
-        k_live = min(self.spec_depth, L - 1)
-        # drafts first — the stream width depends on how many verify lanes
-        # ride this tick
-        drafts: list[tuple[int, Request, np.ndarray]] = []
-        spec_tokens = 0
-        for slot, req in sorted(self.running.items(),
-                                key=lambda sr: sr[1].admit_seq):
-            d_cap = min(k_live, req.max_new_tokens - req.gen_count - 1,
-                        self.cache_len - 1 - int(self.slot_pos[slot]))
-            d = self._drafter.propose(slot, d_cap) if d_cap > 0 \
-                else np.zeros(0, np.int32)
-            drafts.append((slot, req, d))
-            spec_tokens += 1 + len(d)
-        n_dec = len(drafts)
-        budget = max(1, min(int(self.prefill_chunk), self.packed_width))
-        demand = sum(len(r.prompt) - r.prefilled
-                     for r in self.prefilling.values())
-        pre_budget = min(max(1, budget - n_dec), demand) if demand else 0
-        width = min(_bucket(max(1, pre_budget + spec_tokens)),
-                    self.packed_width)
-        width = max(width, pre_budget + spec_tokens)
-        tokens = np.zeros((1, width), np.int32)
-        slot_id = np.full((width,), -1, np.int32)
-        posw = np.zeros((width,), np.int32)
-        start = np.zeros((self.max_batch,), np.int32)
-        seg_len = np.zeros((self.max_batch,), np.int32)
-        is_dec = np.zeros((width,), bool)
-        spec_rows = np.zeros((self.max_batch,), bool)
-        sample = np.zeros((self.max_batch,), bool)
-        gidx = np.full((self.max_batch,), self.cache_len, np.int32)
-        spec_idx = np.zeros((self.max_batch, L), np.int32)
-        draft_len = np.zeros((self.max_batch,), np.int32)
-        done = np.zeros((self.max_batch,), bool)
-        cursor = 0
-        packed: list[tuple[int, Request, int]] = []
-        for slot, req in sorted(self.prefilling.items(),
-                                key=lambda sr: sr[1].admit_seq):
-            if cursor >= pre_budget:
-                break   # later arrivals re-pack from `prefilled` next tick
-            n = min(len(req.prompt) - req.prefilled, pre_budget - cursor)
-            tokens[0, cursor:cursor + n] = \
-                req.prompt[req.prefilled:req.prefilled + n]
-            slot_id[cursor:cursor + n] = slot
-            posw[cursor:cursor + n] = np.arange(req.prefilled,
-                                                req.prefilled + n)
-            start[slot] = req.prefilled
-            seg_len[slot] = n
-            if req.prefilled + n >= len(req.prompt):
-                done[slot] = sample[slot] = True
-                gidx[slot] = 0               # first token -> gen ring head
-                # draft_len = 0, so accept = 0 and the sampled token is the
-                # argmax at the segment's last lane — the first token
-                spec_idx[slot, :] = cursor + n - 1
-            packed.append((slot, req, n))
-            cursor += n
-        pre_cursor = cursor
-        for slot, req, d in drafts:
-            seg = 1 + len(d)
-            spos = int(self.slot_pos[slot])
-            # lane 0 carries a placeholder the jitted step fills from the
-            # device token ring; drafts ride host-side
-            if len(d):
-                tokens[0, cursor + 1:cursor + seg] = d
-            slot_id[cursor:cursor + seg] = slot
-            posw[cursor:cursor + seg] = np.arange(spos, spos + seg)
-            is_dec[cursor] = True
-            start[slot] = spos
-            seg_len[slot] = seg
-            spec_rows[slot] = sample[slot] = True
-            gidx[slot] = min(req.gen_count, self.cache_len)  # ==len => drop
-            spec_idx[slot, :] = cursor + np.minimum(np.arange(L), seg - 1)
-            draft_len[slot] = len(d)
-            cursor += seg
+        with self._span("pack"):
+            L = self._spec_len_max
+            k_live = min(self.spec_depth, L - 1)
+            # drafts first — the stream width depends on how many verify
+            # lanes ride this tick
+            drafts: list[tuple[int, Request, np.ndarray]] = []
+            spec_tokens = 0
+            for slot, req in sorted(self.running.items(),
+                                    key=lambda sr: sr[1].admit_seq):
+                d_cap = min(k_live, req.max_new_tokens - req.gen_count - 1,
+                            self.cache_len - 1 - int(self.slot_pos[slot]))
+                d = self._drafter.propose(slot, d_cap) if d_cap > 0 \
+                    else np.zeros(0, np.int32)
+                drafts.append((slot, req, d))
+                spec_tokens += 1 + len(d)
+            n_dec = len(drafts)
+            budget = max(1, min(int(self.prefill_chunk), self.packed_width))
+            demand = sum(len(r.prompt) - r.prefilled
+                         for r in self.prefilling.values())
+            pre_budget = min(max(1, budget - n_dec), demand) if demand else 0
+            width = min(_bucket(max(1, pre_budget + spec_tokens)),
+                        self.packed_width)
+            width = max(width, pre_budget + spec_tokens)
+            tokens = np.zeros((1, width), np.int32)
+            slot_id = np.full((width,), -1, np.int32)
+            posw = np.zeros((width,), np.int32)
+            start = np.zeros((self.max_batch,), np.int32)
+            seg_len = np.zeros((self.max_batch,), np.int32)
+            is_dec = np.zeros((width,), bool)
+            spec_rows = np.zeros((self.max_batch,), bool)
+            sample = np.zeros((self.max_batch,), bool)
+            gidx = np.full((self.max_batch,), self.cache_len, np.int32)
+            spec_idx = np.zeros((self.max_batch, L), np.int32)
+            draft_len = np.zeros((self.max_batch,), np.int32)
+            done = np.zeros((self.max_batch,), bool)
+            cursor = 0
+            packed: list[tuple[int, Request, int]] = []
+            for slot, req in sorted(self.prefilling.items(),
+                                    key=lambda sr: sr[1].admit_seq):
+                if cursor >= pre_budget:
+                    break   # later arrivals re-pack from `prefilled` next tick
+                n = min(len(req.prompt) - req.prefilled, pre_budget - cursor)
+                tokens[0, cursor:cursor + n] = \
+                    req.prompt[req.prefilled:req.prefilled + n]
+                slot_id[cursor:cursor + n] = slot
+                posw[cursor:cursor + n] = np.arange(req.prefilled,
+                                                    req.prefilled + n)
+                start[slot] = req.prefilled
+                seg_len[slot] = n
+                if req.prefilled + n >= len(req.prompt):
+                    done[slot] = sample[slot] = True
+                    gidx[slot] = 0               # first token -> gen ring head
+                    # draft_len = 0, so accept = 0 and the sampled token is the
+                    # argmax at the segment's last lane — the first token
+                    spec_idx[slot, :] = cursor + n - 1
+                packed.append((slot, req, n))
+                cursor += n
+            pre_cursor = cursor
+            for slot, req, d in drafts:
+                seg = 1 + len(d)
+                spos = int(self.slot_pos[slot])
+                # lane 0 carries a placeholder the jitted step fills from the
+                # device token ring; drafts ride host-side
+                if len(d):
+                    tokens[0, cursor + 1:cursor + seg] = d
+                slot_id[cursor:cursor + seg] = slot
+                posw[cursor:cursor + seg] = np.arange(spos, spos + seg)
+                is_dec[cursor] = True
+                start[slot] = spos
+                seg_len[slot] = seg
+                spec_rows[slot] = sample[slot] = True
+                # ==len => drop
+                gidx[slot] = min(req.gen_count, self.cache_len)
+                spec_idx[slot, :] = cursor + np.minimum(np.arange(L), seg - 1)
+                draft_len[slot] = len(d)
+                cursor += seg
         t_disp = self.clock()
-        if self._tel is not None:
-            self._tel.tracer.phase("dispatch")
-        (self.caches, self._slot_tok, self._gen_buf, accept_d,
-         toks_d) = self._step_spec(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(slot_id), jnp.asarray(posw), jnp.asarray(start),
-            jnp.asarray(seg_len), jnp.asarray(is_dec),
-            jnp.asarray(spec_rows), jnp.asarray(sample), jnp.asarray(gidx),
-            jnp.asarray(spec_idx), jnp.asarray(draft_len), self._slot_tok,
-            self._gen_buf, self._bt() if self.paged else None)
+        with self._span("dispatch", program="spec", width=width):
+            (self.caches, self._slot_tok, self._gen_buf, accept_d,
+             toks_d) = self._step_spec(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(slot_id), jnp.asarray(posw), jnp.asarray(start),
+                jnp.asarray(seg_len), jnp.asarray(is_dec),
+                jnp.asarray(spec_rows), jnp.asarray(sample),
+                jnp.asarray(gidx), jnp.asarray(spec_idx),
+                jnp.asarray(draft_len), self._slot_tok, self._gen_buf,
+                self._bt() if self.paged else None)
         self.model_dispatches += 1
         self._tick_dispatches += 1
         self._prefill_shapes.add(width)
@@ -1837,46 +1873,46 @@ class ServeEngine:
         self._tick_packed_segments += n_dec
         # acceptance decides how far every slot advanced: the one host sync
         # of the tick (accept + per-offset argmaxes feed the drafter)
-        accept = np.asarray(accept_d)
-        tks = np.asarray(toks_d)
-        if self._tel is not None:
-            self._tel.tracer.phase("sample")
-        if n_dec:
-            dt = self.clock() - t_disp
-            self.decode_latency.record(dt)
-            if self._tel is not None and dt > 0.0:
-                self._tel_h_decode.record(dt)
-        now = self.clock()
-        for slot, req, n in packed:
-            req.prefilled += n
-            req.prefill_chunks += 1
-            if done[slot]:
-                req.gen_count = 1            # first token is on device
-                self._stamp_first_token(req, now)
-                self.slot_pos[slot] = len(req.prompt)
-                self.running[slot] = self.prefilling.pop(slot)
-                self._cache_insert(req)
-                self._drafter.begin(slot, req)
-                self._drafter.extend(slot, tks[slot, :1])
-        n_emitted = 0
-        for slot, req, d in drafts:
-            a = int(accept[slot])
-            self._drafter.extend(slot, tks[slot, :a + 1])
-            self.slot_pos[slot] += a + 1
-            req.gen_count += a + 1
-            n_emitted += a + 1
-            self._tick_spec_proposed += len(d)
-            self._tick_spec_accepted += a
-            if self._tel is not None:
-                self._tel_h_spec.record(float(a))
-        if self._tick_spec_proposed:
-            self.spec_proposed += self._tick_spec_proposed
-            self.spec_accepted += self._tick_spec_accepted
-            self._accept_window.append((self._tick_spec_accepted,
-                                        self._tick_spec_proposed))
-            if self._tel is not None:
-                self._tel_c_spec_prop.inc(self._tick_spec_proposed)
-                self._tel_c_spec_acc.inc(self._tick_spec_accepted)
+        with self._span("fetch"):
+            accept = np.asarray(accept_d)
+            tks = np.asarray(toks_d)
+        with self._span("sample"):
+            if n_dec:
+                dt = self.clock() - t_disp
+                self.decode_latency.record(dt)
+                if self._tel is not None and dt > 0.0:
+                    self._tel_h_decode.record(dt)
+            now = self.clock()
+            for slot, req, n in packed:
+                req.prefilled += n
+                req.prefill_chunks += 1
+                if done[slot]:
+                    req.gen_count = 1            # first token is on device
+                    self._stamp_first_token(req, now)
+                    self.slot_pos[slot] = len(req.prompt)
+                    self.running[slot] = self.prefilling.pop(slot)
+                    self._cache_insert(req)
+                    self._drafter.begin(slot, req)
+                    self._drafter.extend(slot, tks[slot, :1])
+            n_emitted = 0
+            for slot, req, d in drafts:
+                a = int(accept[slot])
+                self._drafter.extend(slot, tks[slot, :a + 1])
+                self.slot_pos[slot] += a + 1
+                req.gen_count += a + 1
+                n_emitted += a + 1
+                self._tick_spec_proposed += len(d)
+                self._tick_spec_accepted += a
+                if self._tel is not None:
+                    self._tel_h_spec.record(float(a))
+            if self._tick_spec_proposed:
+                self.spec_proposed += self._tick_spec_proposed
+                self.spec_accepted += self._tick_spec_accepted
+                self._accept_window.append((self._tick_spec_accepted,
+                                            self._tick_spec_proposed))
+                if self._tel is not None:
+                    self._tel_c_spec_prop.inc(self._tick_spec_proposed)
+                    self._tel_c_spec_acc.inc(self._tick_spec_accepted)
         self._tick_spec_lanes = spec_tokens - n_dec
         self._tick_decode = n_emitted
         self._tick_decode_slots = n_dec
@@ -1890,24 +1926,27 @@ class ServeEngine:
         """Advance every prefilling slot by one chunk in a single padded
         call.  The chunk width is the power-of-two bucket covering the
         largest chunk this tick, so mixed prompt lengths reuse compiles."""
-        cap = max(1, int(self.prefill_chunk))
-        width = _bucket(max(min(len(r.prompt) - r.prefilled, cap)
-                            for r in self.prefilling.values()))
-        tokens = np.zeros((self.max_batch, width), np.int32)
-        start = np.zeros((self.max_batch,), np.int32)
-        lengths = np.zeros((self.max_batch,), np.int32)
-        done = np.zeros((self.max_batch,), bool)
-        for slot, req in self.prefilling.items():
-            n = min(len(req.prompt) - req.prefilled, cap, width)
-            tokens[slot, :n] = req.prompt[req.prefilled:req.prefilled + n]
-            start[slot] = req.prefilled
-            lengths[slot] = n
-            done[slot] = req.prefilled + n >= len(req.prompt)
-        self.caches, self._slot_tok, self._gen_buf = self._prefill_chunk(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(start), jnp.asarray(lengths), jnp.asarray(done),
-            self._slot_tok, self._gen_buf,
-            self._bt() if self.paged else None)
+        with self._span("pack"):
+            cap = max(1, int(self.prefill_chunk))
+            width = _bucket(max(min(len(r.prompt) - r.prefilled, cap)
+                                for r in self.prefilling.values()))
+            tokens = np.zeros((self.max_batch, width), np.int32)
+            start = np.zeros((self.max_batch,), np.int32)
+            lengths = np.zeros((self.max_batch,), np.int32)
+            done = np.zeros((self.max_batch,), bool)
+            for slot, req in self.prefilling.items():
+                n = min(len(req.prompt) - req.prefilled, cap, width)
+                tokens[slot, :n] = req.prompt[req.prefilled:
+                                              req.prefilled + n]
+                start[slot] = req.prefilled
+                lengths[slot] = n
+                done[slot] = req.prefilled + n >= len(req.prompt)
+        with self._span("dispatch", program="prefill", width=width):
+            self.caches, self._slot_tok, self._gen_buf = self._prefill_chunk(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(start), jnp.asarray(lengths), jnp.asarray(done),
+                self._slot_tok, self._gen_buf,
+                self._bt() if self.paged else None)
         self.prefill_calls += 1
         self.model_dispatches += 1
         self._tick_dispatches += 1
@@ -1918,18 +1957,20 @@ class ServeEngine:
         if done.any():
             # a first token is a completion boundary: wait for the device
             # (no host transfer) so TTFT reflects compute, not dispatch
-            self._slot_tok.block_until_ready()
-        now = self.clock()
-        for slot in list(self.prefilling):
-            req = self.prefilling[slot]
-            req.prefilled += int(lengths[slot])
-            req.prefill_chunks += 1
-            if done[slot]:
-                req.gen_count = 1            # first token is on device
-                self._stamp_first_token(req, now)
-                self.slot_pos[slot] = len(req.prompt)
-                self.running[slot] = self.prefilling.pop(slot)
-                self._cache_insert(req)
+            with self._span("wait"):
+                self._slot_tok.block_until_ready()
+        with self._span("sample"):
+            now = self.clock()
+            for slot in list(self.prefilling):
+                req = self.prefilling[slot]
+                req.prefilled += int(lengths[slot])
+                req.prefill_chunks += 1
+                if done[slot]:
+                    req.gen_count = 1            # first token is on device
+                    self._stamp_first_token(req, now)
+                    self.slot_pos[slot] = len(req.prompt)
+                    self.running[slot] = self.prefilling.pop(slot)
+                    self._cache_insert(req)
 
     def _cache_insert(self, req: Request) -> None:
         """Prefill-complete hook: adopt the finished prompt's full-block
@@ -1957,15 +1998,18 @@ class ServeEngine:
         if self.cfg.encoder_decoder:
             batch["frames"] = jnp.zeros(
                 (1, self.cfg.enc_seq, self.cfg.d_model), jnp.float32)
-        logits, one_cache = self._prefill(self.params, batch)
-        self.caches = self._merge(self.caches, one_cache,
-                                  jnp.asarray(req.slot, jnp.int32))
+        with self._span("dispatch", program="prefill",
+                        width=len(req.prompt)):
+            logits, one_cache = self._prefill(self.params, batch)
+            self.caches = self._merge(self.caches, one_cache,
+                                      jnp.asarray(req.slot, jnp.int32))
         self.prefill_calls += 1
         self.model_dispatches += 1
         self._tick_dispatches += 1
         self._prefill_shapes.add(len(req.prompt))
         self._record_prefill_pad(len(req.prompt), len(req.prompt), 1)
-        first = int(jnp.argmax(logits[0]))
+        with self._span("fetch"):
+            first = int(jnp.argmax(logits[0]))
         self._slot_tok = self._slot_tok.at[req.slot].set(first)
         self._gen_buf = self._gen_buf.at[req.slot, 0].set(first)
         req.gen_count = 1
@@ -1979,23 +2023,27 @@ class ServeEngine:
     def _decode_tick(self) -> int:
         if not self.running:
             return 0
-        active = np.zeros((self.max_batch,), bool)
-        gidx = np.full((self.max_batch,), self.cache_len, np.int32)
-        for slot, req in self.running.items():
-            active[slot] = True
-            gidx[slot] = min(req.gen_count, self.cache_len)  # ==len => drop
-        pos = jnp.asarray(np.maximum(self.slot_pos, 0).astype(np.int32))
+        with self._span("pack"):
+            active = np.zeros((self.max_batch,), bool)
+            gidx = np.full((self.max_batch,), self.cache_len, np.int32)
+            for slot, req in self.running.items():
+                active[slot] = True
+                # ==len => drop
+                gidx[slot] = min(req.gen_count, self.cache_len)
+            pos = np.maximum(self.slot_pos, 0).astype(np.int32)
         # the decode-only latency sensor wraps just the dispatch + device
         # wait (no host transfer): the sc_chunk controller acting on its
         # p99 sees real decode compute, not admission/scheduling host work
         # (that whole-tick span is tick_latency's job)
         t_disp = self.clock()
         with self.decode_latency.measure():
-            self._slot_tok, self.caches, self._gen_buf = self._decode(
-                self.params, self.caches, self._slot_tok, pos,
-                jnp.asarray(active), self._gen_buf, jnp.asarray(gidx),
-                self._bt() if self.paged else None)
-            self._slot_tok.block_until_ready()
+            with self._span("dispatch", program="decode", width=None):
+                self._slot_tok, self.caches, self._gen_buf = self._decode(
+                    self.params, self.caches, self._slot_tok,
+                    jnp.asarray(pos), jnp.asarray(active), self._gen_buf,
+                    jnp.asarray(gidx), self._bt() if self.paged else None)
+            with self._span("wait"):
+                self._slot_tok.block_until_ready()
         if self._tel is not None:
             dt = self.clock() - t_disp
             if dt > 0.0:
@@ -2004,10 +2052,11 @@ class ServeEngine:
         self._tick_dispatches += 1
         self._decode_dispatched = True
         n = 0
-        for slot, req in self.running.items():
-            self.slot_pos[slot] += 1
-            req.gen_count += 1
-            n += 1
+        with self._span("sample"):
+            for slot, req in self.running.items():
+                self.slot_pos[slot] += 1
+                req.gen_count += 1
+                n += 1
         self._tick_decode = n
         self._tick_decode_slots = n
         self.throughput.record(n)
@@ -2019,7 +2068,8 @@ class ServeEngine:
         if not done:
             return
         # completion boundary: the only device->host token sync in the loop
-        gen = np.asarray(self._gen_buf)
+        with self._span("fetch"):
+            gen = np.asarray(self._gen_buf)
         for slot, req in done:
             req.done_t = self.clock()
             # the prefill tick also decodes, so gen_count can overshoot

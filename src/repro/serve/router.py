@@ -55,7 +55,8 @@ __all__ = ["ReplicaRouter"]
 
 # merged-stats policy: these fields describe the tick's *compute cost* and
 # come from the max-cost replica (concurrent replicas: the slowest one sets
-# the tick's wall time); everything countable sums; the rest is max/any.
+# the tick's wall time); everything countable sums, and so does host_s (one
+# host ticks the replicas in turn); the rest is max/any.
 _COST_KEYS = ("pad_fraction", "dispatches", "prefill_tokens",
               "prefill_issued_tokens", "decode_slots", "spec_lanes",
               "spec_depth", "accept_rate", "kv_cache_share")
@@ -63,7 +64,7 @@ _SUM_KEYS = ("queued", "waiting", "running", "finished", "tokens", "hbm",
              "packed_segments", "decode_tokens", "kv_used_blocks",
              "kv_budget_blocks", "kv_capacity_blocks", "kv_frag_tokens",
              "preemptions", "rejected", "slo_good_tokens", "slo_miss_tokens",
-             "prefix_hit_tokens", "prefix_cache_blocks")
+             "prefix_hit_tokens", "prefix_cache_blocks", "host_s")
 
 
 class ReplicaRouter:

@@ -2,6 +2,7 @@
 determinism (byte-identical artifacts under VirtualClock), the TickStats
 schema freeze, and the profiler's metrics emission."""
 
+import contextlib
 import json
 import os
 
@@ -69,29 +70,39 @@ def test_metrics_registry_get_or_create_and_write(tmp_path):
 def test_tracer_tick_spans_and_schema():
     t = [0.0]
     trc = Tracer(clock=lambda: t[0])
-    trc.begin_tick(0)
-    for name in ("admit", "pack", "dispatch"):
-        trc.phase(name)
-    t[0] = 0.03
-    trc.end_tick(args={"tokens": 5})
+    with trc.span("tick 0") as args:
+        # each phase ends where the clock stood at its exit: the phases'
+        # own times, not slices of the tick
+        for name, end in (("admit", 0.001), ("pack", 0.004),
+                          ("dispatch", 0.025)):
+            with trc.span(name, {"tick": 0}):
+                t[0] = end
+        t[0] = 0.03
+        args["tokens"] = 5
+    with pytest.raises(RuntimeError):
+        with trc.span("sample", {"tick": 1}):
+            t[0] = 0.031
+            raise RuntimeError("a span closes on an exception")
+    with trc.span("finish", {"tick": 1}):
+        pass                               # a frozen clock: zero-length
     trc.instant("chaos:slow_tick", tid=Tracer.TID_CHAOS)
     trc.async_begin("request", 7, args={"tier": 0})
     trc.async_end("request", 7)
     evs = trc.events
-    spans = [e for e in evs if e["ph"] == "X"]
-    assert [e["name"] for e in spans] == ["tick 0", "admit", "pack",
-                                         "dispatch"]
-    tick = spans[0]
-    assert tick["dur"] == 30_000          # 0.03s of virtual time, in us
-    # phases tile the tick span exactly: ordering is the ground truth
-    assert sum(e["dur"] for e in spans[1:]) == tick["dur"]
-    assert spans[1]["ts"] == tick["ts"]
+    spans = {e["name"]: e for e in evs if e["ph"] == "X"}
+    assert list(spans) == ["admit", "pack", "dispatch", "tick 0", "sample",
+                           "finish"]       # written as each span closes
+    assert [(spans[n]["ts"], spans[n]["dur"]) for n in spans] == [
+        (0, 1_000), (1_000, 3_000), (4_000, 21_000), (0, 30_000),
+        (30_000, 1_000), (31_000, 0)]
+    assert spans["tick 0"]["args"] == {"tokens": 5}
+    assert spans["pack"]["args"] == {"tick": 0}
     for e in evs:                          # trace-event required fields
         assert {"name", "ph", "pid", "tid"} <= set(e)
         if e["ph"] != "M":
             assert isinstance(e["ts"], int)
         if e["ph"] == "X":
-            assert isinstance(e["dur"], int) and e["dur"] >= 1
+            assert isinstance(e["dur"], int) and e["dur"] >= 0
         if e["ph"] in ("b", "e"):
             assert "id" in e and "cat" in e
     doc = trc.to_json()
@@ -335,12 +346,13 @@ def test_telemetry_deterministic_under_virtual_clock(small_model, tmp_path):
     assert open(paths_a["flight"], "rb").read() == \
         open(paths_b["flight"], "rb").read()
     # virtual timestamps: the span sequence is identical, and every complete
-    # event in the written artifact satisfies the trace-event schema
+    # event in the written artifact satisfies the trace-event schema; the
+    # clock is frozen within a tick, so every measured span is zero-length
     doc = json.load(open(paths_a["trace"]))
     for e in doc["traceEvents"]:
         assert {"name", "ph", "pid", "tid"} <= set(e)
         if e["ph"] == "X":
-            assert e["dur"] >= 1 and e["ts"] >= 0
+            assert e["dur"] == 0 and e["ts"] >= 0
     assert doc["otherData"]["dropped_events"] == 0
     # the audit log is replayable: decisions arrive in tick order
     ticks = [json.loads(x)["tick"] for x in open(paths_a["audit"])]
@@ -370,3 +382,123 @@ def test_profile_buffer_emits_flush_metrics(tmp_path):
     assert len(buf.samples) == 9
     assert m.counter("profiler.t.knob.samples").value == 9
     assert m.counter("profiler.t.knob.flushes").value == 3   # 4 + 4 + 1
+
+
+# ------------------------------------------------------ engine phase spans
+
+def _paged_engine(cfg, params, **kw):
+    return ServeEngine(cfg, params, max_batch=2, cache_len=64,
+                       block_tokens=16, kv_mode="paged",
+                       prefill_mode="packed", enable_smartconf=False, **kw)
+
+
+def _serve(eng, rng, cfg, first_id):
+    for i in range(2):
+        assert eng.submit(_req(rng, cfg, first_id + i, plen=12, new=4))
+    stats = []
+    while len(eng.finished) < first_id + 2:
+        stats.append(eng.tick())
+    return stats
+
+
+def _host_spans(trace_dir):
+    """(name, start_ns, end_ns, args) of every serve.* span, by start."""
+    from jax.profiler import ProfileData
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+               for f in fs if f.endswith(".xplane.pb")]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats)) for e in line.events
+                       if e.name.startswith("serve."))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def test_phase_spans_nest_in_the_profiler_trace(small_model, rng, tmp_path):
+    """A few ticks traced with jax.profiler: each serve.tick holds its
+    phases in order, serve.fetch lies inside serve.finish, serve.wait
+    inside the tick, and serve.dispatch names its program and width."""
+    cfg, params = small_model
+    eng = _paged_engine(cfg, params)
+    _serve(eng, rng, cfg, 0)                   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        stats = _serve(eng, rng, cfg, 2)
+    eng.close()
+    spans = _host_spans(str(tmp_path))
+    ticks = [s for s in spans if s[0] == "serve.tick"]
+    assert [t[3]["step_num"] for t in ticks] == [s["tick"] for s in stats]
+    phases = []
+    for _, a, b, args in ticks:
+        inside = [s for s in spans if a <= s[1] and s[2] <= b
+                  and s[0] != "serve.tick"]
+        names = [s[0] for s in inside]
+        phases.append(names)
+        assert names[:3] == ["serve.control", "serve.admit",
+                             "serve.schedule"]
+        assert names[3:6] == ["serve.pack", "serve.dispatch", "serve.wait"]
+        assert names[-1] == "serve.finish" or names[-2:] == [
+            "serve.finish", "serve.fetch"]
+        for n, fa, fb, _ in inside:
+            if n == "serve.fetch":              # the token read, in finish
+                (fin,) = [s for s in inside if s[0] == "serve.finish"]
+                assert fin[1] <= fa and fb <= fin[2]
+        # the host's part leaves out the waits, and only the waits
+        waited = sum(fb - fa for n, fa, fb, _ in inside
+                     if n in ("serve.wait", "serve.fetch"))
+        assert waited < b - a
+    dispatches = [s[3] for s in spans if s[0] == "serve.dispatch"]
+    assert dispatches[0] == {"program": "unified", "width": 32}   # 2 x 12
+    assert {"program": "decode", "width": "None"} in dispatches
+    assert {d["program"] for d in dispatches} == {"unified", "decode"}
+    assert sum("serve.fetch" in p for p in phases) == 1
+    assert all(0.0 < s["host_s"] for s in stats)
+
+
+def test_host_s_leaves_out_the_device_waits(small_model, rng):
+    """host_s is the tick's time less its wait and fetch spans, on the
+    engine's clock: a clock that moves 1 s per reading inside a wait span
+    and not elsewhere gives host_s 0."""
+    cfg, params = small_model
+    t = [0.0]
+    eng = _paged_engine(cfg, params, clock=lambda: t[0])
+    span = eng._span
+
+    @contextlib.contextmanager
+    def waiting_span(phase, **args):
+        with span(phase, **args) as note:
+            if phase in ("wait", "fetch"):
+                t[0] += 1.0
+            yield note
+
+    eng._span = waiting_span
+    for s in _serve(eng, rng, cfg, 0):
+        assert s["host_s"] == 0.0
+    eng.close()
+
+
+def test_programs_keep_the_names_the_benchmark_reads(small_model, rng):
+    """The device trace names a program after its jitted function: the
+    benchmark finds the unified tick as jit_step_unified_fn and the
+    decode-only tick as jit_decode_fn."""
+    cfg, params = small_model
+    eng = _paged_engine(cfg, params)
+    modules = {}
+
+    def spy(attr):
+        fn = getattr(eng, attr)
+
+        def call(*args):
+            modules.setdefault(attr, fn.lower(*args).compile().as_text())
+            return fn(*args)
+        setattr(eng, attr, call)
+
+    spy("_step_unified")
+    spy("_decode")
+    _serve(eng, rng, cfg, 0)
+    eng.close()
+    heads = {k: v.split("\n", 1)[0] for k, v in modules.items()}
+    assert heads["_step_unified"].startswith("HloModule jit_step_unified_fn,")
+    assert heads["_decode"].startswith("HloModule jit_decode_fn,")

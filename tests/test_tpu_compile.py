@@ -12,6 +12,7 @@ described.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,42 +60,54 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
+
+
+def _names_kernel(text, name):
+    """The device trace names a kernel's operation after its compiled
+    custom call; the benchmark finds the serving kernels by these names."""
+    assert re.search(rf"^\s*(ROOT )?%{name}(\.\d+)? = \S+ custom-call\(",
+                     text, re.M), f"no custom call named {name}"
 
 
 @pytest.mark.parametrize("p", [8, 256])
 def test_segment_attention_flat(one_chip, p):
     n = B * 256 + p          # every slot's ring ++ the stream's own keys
-    _compile(lambda q, k, v, qp, kp, qs, ks: segment_attention(
+    text = _compile(lambda q, k, v, qp, kp, qs, ks: segment_attention(
         q, k, v, qp, kp, qs, ks), one_chip,
         ((p, H, D), jnp.bfloat16), ((n, KV, D), jnp.bfloat16),
         ((n, KV, D), jnp.bfloat16), ((p,), jnp.int32), ((n,), jnp.int32),
         ((p,), jnp.int32), ((n,), jnp.int32))
+    _names_kernel(text, "segment_attention")
 
 
 @pytest.mark.parametrize("p", [8, 256])
 def test_segment_attention_paged(one_chip, p):
-    _compile(lambda q, k, v, bt, qp, qs: paged_segment_attention(
+    text = _compile(lambda q, k, v, bt, qp, qs: paged_segment_attention(
         q, k, v, bt, qp, qs), one_chip,
         ((p, H, D), jnp.bfloat16), ((NB, KV, T, D), jnp.bfloat16),
         ((NB, KV, T, D), jnp.bfloat16), ((B, M), jnp.int32),
         ((p,), jnp.int32), ((p,), jnp.int32))
+    _names_kernel(text, "paged_segment_attention")
 
 
 def test_paged_decode_attention(one_chip):
-    _compile(lambda q, k, v, bt, qp: paged_decode_attention(
+    text = _compile(lambda q, k, v, bt, qp: paged_decode_attention(
         q, k, v, bt, qp), one_chip,
         ((B, H, D), jnp.bfloat16), ((NB, KV, T, D), jnp.bfloat16),
         ((NB, KV, T, D), jnp.bfloat16), ((B, M), jnp.int32),
         ((B,), jnp.int32))
+    _names_kernel(text, "paged_decode_attention")
 
 
 def test_decode_attention(one_chip):
     s = M * T
-    _compile(lambda q, k, v, kp, qp: decode_attention(q, k, v, kp, qp),
+    text = _compile(lambda q, k, v, kp, qp: decode_attention(q, k, v, kp, qp),
              one_chip,
              ((B, H, D), jnp.bfloat16), ((B, KV, s, D), jnp.bfloat16),
              ((B, KV, s, D), jnp.bfloat16), ((B, s), jnp.int32),
              ((B,), jnp.int32))
+    _names_kernel(text, "decode_attention")
 
 
 def test_rwkv6_scan_state(one_chip):
