@@ -124,7 +124,10 @@ from repro.core.telemetry import Telemetry, Tracer
 from repro.distributed.fault_tolerance import PreemptionHandler
 from repro.distributed.sharding import SERVE_TP_RULES, use_mesh
 from repro.kernels.decode_attention import padded_cache_len
+from repro.kernels.segment_attention.segment_attention import (
+    live_block_ranges, live_blocks)
 from repro.models import zoo
+from repro.models.blocks import ATTN_KINDS
 from .block_store import CacheShardingPlan, build_serve_mesh
 from .kv_cache import KVBlockPool, QUEUE_TOKEN_BYTES
 from .options import ServeOptions, SLOSpec
@@ -167,6 +170,10 @@ TICK_STATS_KEYS: tuple[str, ...] = (
     # appended (engine phase spans): seconds of the tick's serve.tick span
     # outside its serve.wait and serve.fetch spans — the host's part
     "host_s",
+    # appended (live paged walk): KV blocks the packed tick's paged
+    # segment attention visits per query head, mean over attention layers
+    # (0 on ticks that do not run it)
+    "attn_kv_blocks",
 )
 
 # phases whose span is the host waiting on the device: a tick's host_s
@@ -478,6 +485,14 @@ class ServeEngine:
         self._tick_live = 0
         self._tick_packed_segments = 0
         self._tick_decode = 0
+        self._tick_kv_blocks = 0.0
+        # the window each attention layer's paged walk is bounded by, with
+        # its layer count (models/blocks.py: swa/local kinds take cfg.window)
+        self._attn_windows = collections.Counter(
+            cfg.window if base in ("swa", "local") else 0
+            for base in (cfg.block_pattern[i % len(cfg.block_pattern)]
+                         .split("+")[0] for i in range(cfg.num_layers))
+            if base in ATTN_KINDS)
 
         # device-resident hot state (one fused batch across slots); the
         # host only keeps positions/counters, never token values
@@ -982,6 +997,7 @@ class ServeEngine:
         self._tick_prefix_hit = 0
         self._tick_spec_proposed = self._tick_spec_accepted = 0
         self._tick_spec_lanes = self._tick_decode_slots = 0
+        self._tick_kv_blocks = 0.0
         tel = self._tel
         if tel is not None:
             tel.audit.tick = self.ticks_run
@@ -1086,6 +1102,7 @@ class ServeEngine:
             # the host's part of the tick: tick() sets it once the tick's
             # span has closed
             "host_s": 0.0,
+            "attn_kv_blocks": self._tick_kv_blocks,
         }
 
     def run(self, ticks: int) -> list[dict]:
@@ -1708,6 +1725,7 @@ class ServeEngine:
                 gidx[slot] = min(req.gen_count, self.cache_len)
                 decoders.append((slot, req))
                 cursor += 1
+            self._count_kv_blocks(slot_id, posw)
         t_disp = self.clock()
         with self._span("dispatch", program="unified", width=width):
             self.caches, self._slot_tok, self._gen_buf = self._step_unified(
@@ -1756,6 +1774,19 @@ class ServeEngine:
         if n_tokens:
             self.throughput.record(n_tokens)
         return n_tokens
+
+    def _count_kv_blocks(self, slot_id: np.ndarray, posw: np.ndarray):
+        """The tick stat ``attn_kv_blocks``: blocks the paged segment
+        kernel walks for this stream, by the kernel's own tiling rule, per
+        query head and averaged over the attention layers."""
+        if not self.paged:
+            return
+        walked = sum(n * live_blocks(live_block_ranges(
+            posw, slot_id, num_slots=self.max_batch,
+            max_blocks=self.blocks_per_seq,
+            block_tokens=self.pool.block_tokens, window=w))
+            for w, n in self._attn_windows.items())
+        self._tick_kv_blocks = walked / sum(self._attn_windows.values())
 
     # --------------------------------- speculative prefill+decode stream
     def _tick_spec(self) -> int:
@@ -1852,6 +1883,7 @@ class ServeEngine:
                 spec_idx[slot, :] = cursor + np.minimum(np.arange(L), seg - 1)
                 draft_len[slot] = len(d)
                 cursor += seg
+            self._count_kv_blocks(slot_id, posw)
         t_disp = self.clock()
         with self._span("dispatch", program="spec", width=width):
             (self.caches, self._slot_tok, self._gen_buf, accept_d,

@@ -64,7 +64,8 @@ _SUM_KEYS = ("queued", "waiting", "running", "finished", "tokens", "hbm",
              "packed_segments", "decode_tokens", "kv_used_blocks",
              "kv_budget_blocks", "kv_capacity_blocks", "kv_frag_tokens",
              "preemptions", "rejected", "slo_good_tokens", "slo_miss_tokens",
-             "prefix_hit_tokens", "prefix_cache_blocks", "host_s")
+             "prefix_hit_tokens", "prefix_cache_blocks", "host_s",
+             "attn_kv_blocks")
 
 
 class ReplicaRouter:

@@ -255,6 +255,7 @@ def test_tick_stats_schema_frozen(small_model):
     # Growing it is fine (append + update the tuple); renames/removals
     # break downstream consumers of tick()'s return value.
     assert tuple(eng._stats(0)) == TICK_STATS_KEYS
+    assert TICK_STATS_KEYS[-2:] == ("host_s", "attn_kv_blocks")
     stats = eng.tick()
     assert tuple(stats) == TICK_STATS_KEYS
     assert stats["tick"] == 0 and eng.ticks_run == 1

@@ -81,14 +81,24 @@ def test_segment_attention_flat(one_chip, p):
     _names_kernel(text, "segment_attention")
 
 
-@pytest.mark.parametrize("p", [8, 256])
-def test_segment_attention_paged(one_chip, p):
+@pytest.mark.parametrize("p,h,b,m", [
+    pytest.param(8, H, B, M, id="8"),
+    pytest.param(256, H, B, M, id="256"),
+    # starcoder2-15b: 48/4 heads of 128, 8 slots of 256 blocks, the
+    # benchmark's long prefill widths
+    pytest.param(2048, 48, 8, 256, id="sc2-2048"),
+    pytest.param(4096, 48, 8, 256, id="sc2-4096"),
+])
+def test_segment_attention_paged(one_chip, p, h, b, m):
     text = _compile(lambda q, k, v, bt, qp, qs: paged_segment_attention(
         q, k, v, bt, qp, qs), one_chip,
-        ((p, H, D), jnp.bfloat16), ((NB, KV, T, D), jnp.bfloat16),
-        ((NB, KV, T, D), jnp.bfloat16), ((B, M), jnp.int32),
+        ((p, h, D), jnp.bfloat16), ((NB, KV, T, D), jnp.bfloat16),
+        ((NB, KV, T, D), jnp.bfloat16), ((b, m), jnp.int32),
         ((p,), jnp.int32), ((p,), jnp.int32))
     _names_kernel(text, "paged_segment_attention")
+    # one kernel per call: the benchmark's time for it is this one op
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
 
 
 def test_paged_decode_attention(one_chip):
