@@ -15,10 +15,8 @@ import time
 
 import numpy as np
 
-from . import cells, loadgen, reference, trace as trace_mod
+from . import cells, loadgen, trace as trace_mod
 from .peaks import peaks_for
-from .shape import Shape
-from .weights import init_weights
 
 # device memory the serving budget leaves to what the engine's own ledger
 # does not count (a tick's temporaries, the runtime's reservations)
@@ -89,27 +87,6 @@ class CompileLog:
     def between(self, lo: float, hi: float) -> tuple[int, int]:
         return (sum(lo <= t <= hi for t, _ in self.compiles),
                 sum(lo <= t <= hi for t in self.cache_hits))
-
-
-def program_config(config: dict, shape: Shape):
-    """The program's own configuration of this architecture, cut to the
-    configuration file's depth; every width must agree with the file."""
-    from repro.configs import get_config
-    prog = config["program"]
-    cfg = dataclasses.replace(get_config(prog["arch"]),
-                              num_layers=shape.layers,
-                              **prog.get("replace", {}))
-    got = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-           cfg.resolved_head_dim, cfg.d_ff, cfg.vocab_size, cfg.norm,
-           cfg.mlp == "swiglu", cfg.rope_theta, cfg.dtype,
-           cfg.tie_embeddings, tuple(cfg.block_pattern))
-    want = (shape.d_model, shape.heads, shape.kv_heads, shape.head_dim,
-            shape.d_ff, shape.vocab, shape.norm, shape.gated,
-            shape.rope_theta, shape.dtype, False, ("full",))
-    if got != want:
-        raise ValueError(f"program config {prog['arch']} {got} differs from "
-                         f"the configuration file {want}")
-    return cfg
 
 
 def hbm_budget(device) -> int:
@@ -222,7 +199,7 @@ class GcLog:
 class RunData:
     """What the metric readers read (``bench/metrics/<name>.py``)."""
     cell: cells.Cell
-    shape: Shape
+    shape: object          # the family's shape (``cell.family``)
     peaks: object | None
     records: list          # ticks of the window
     gaps: list             # inter-token gaps (s) whose later token is in it
@@ -274,14 +251,14 @@ def verdict(result: dict, failed: int = 0) -> bool:
             and result["widest_gap_logits"] <= result["limit"])
 
 
-def check(shape: Shape, weights, sample, limit: float, length: int,
+def check(family, shape, weights, sample, limit: float, length: int,
           rows: int, control: bool = False) -> dict:
     """Widest gap, in logits, between the reference's best token and the
     token served, over every served token of the sample (with ``control``,
     the token the lower-precision control puts first), with the mean gap
     and the share of tokens that are not the reference's best beside it."""
-    gaps = [reference.served_gaps(shape, weights, r.prompt, r.generated,
-                                  length=length, rows=rows, control=control)
+    gaps = [family.served_gaps(shape, weights, r.prompt, r.generated,
+                               length=length, rows=rows, control=control)
             for r in sample]
     flat = np.concatenate(gaps) if gaps else np.zeros((0,))
     return {"widest_gap_logits": float(flat.max()) if len(flat) else 0.0,
@@ -303,20 +280,18 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     dev = devs[0]
     cache_dir = enable_compile_cache() if require_tpu else None
     clog = CompileLog()
-    shape = Shape.from_config(cell.config)
+    family = cell.family
+    shape = family.shape_from_config(cell.config)
     serve = cell.config["serve"]
-    cfg = program_config(cell.config, shape)
+    cfg = family.program_config(cell.config, shape)
     peaks = peaks_for(dev.device_kind) if require_tpu else None
     log(f"devices: {len(devs)} x {dev.device_kind} ({dev.platform}); "
         f"compile cache {cache_dir}")
 
     t = time.perf_counter()
-    weights = init_weights(shape, seed)
+    weights = family.init_weights(shape, seed)
     jax.block_until_ready(weights)
-    log(f"weights: {shape.layers} layers, d {shape.d_model}, "
-        f"{shape.heads}/{shape.kv_heads} heads x {shape.head_dim}, d_ff "
-        f"{shape.d_ff}, vocab {shape.vocab} in {time.perf_counter() - t:.2f}"
-        " s")
+    log(f"weights: {shape} in {time.perf_counter() - t:.2f} s")
     eng = build_engine(cfg, weights, serve, hbm_budget(dev))
     t = time.perf_counter()
     widths = warm_up(eng, shape.vocab, np.random.default_rng([seed, 3]))
@@ -431,7 +406,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     # one reference program per cell: every sequence padded to the cache,
     # the served tokens to the mix's longest output
     dims = (serve["cache_len"], int(cell.mix["output"]["hi"]))
-    result = check(shape, weights, sample, limit, *dims)
+    result = check(family, shape, weights, sample, limit, *dims)
     log(f"reference: {result['requests']} requests, {result['tokens']} "
         f"served tokens in {time.perf_counter() - t:.2f} s; mean gap "
         f"{result['mean_gap_logits']}, not the best for "
@@ -445,7 +420,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     if breakdown is not None:
         line["breakdown"] = breakdown
     if control:
-        ctrl = check(shape, weights, sample, limit, *dims, control=True)
+        ctrl = check(family, shape, weights, sample, limit, *dims,
+                     control=True)
         result["correct"] = correct
         ctrl["correct"] = verdict(ctrl, failed)
         line["program_check"] = result

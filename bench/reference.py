@@ -1,29 +1,25 @@
 """The plain reference the served tokens are judged against, and its
-lower-precision control.
+lower-precision control: the pieces every family's forward is written from
+(``bench/families/<family>.py``), and the gap arithmetic.
 
-``logits`` is the published architecture written out in ``jax.numpy`` at
-float32 (``jax.default_matmul_precision("highest")``): token embedding;
-per layer a pre-norm (RMSNorm or LayerNorm), grouped-query causal attention
-with rotary positions (rotate-half, ``rope_theta``), a residual, a second
-pre-norm and the MLP (SwiGLU, or GELU with the tanh approximation), a
-residual; a final norm and the untied head.  It imports nothing of the
-program and reads only the weights the benchmark made.  Departures from the
-published models (noted in each configuration file) are shared with the
-program: no linear biases, and full attention in place of a sliding window
-that no sequence of the cell reaches.
+A family's ``_logits`` is its published architecture written out in
+``jax.numpy`` at float32 (``jax.default_matmul_precision("highest")``).  It
+imports nothing of the program and reads only the weights the benchmark
+made.  Departures from the published models (noted in each configuration
+file) are shared with the program.
 
 ``control=True`` is the same forward computed one precision step below the
-configuration's bfloat16: every linear layer (attention projections, MLP,
-head) takes fp8 (e4m3) operands, weights scaled per output channel and
-activations per token, accumulated in float32.  (int8 with the same
-scales was tried first and reads within 1.6 times the served path's gaps
-on yi-6b, and 0 on some starcoder2 seeds: it does not separate.)
+configuration's bfloat16: every linear layer (``linear``) takes fp8 (e4m3)
+operands, weights scaled per output channel and activations per token,
+accumulated in float32.  (int8 with the same scales was tried first and
+reads within 1.6 times the served path's gaps on yi-6b, and 0 on some
+starcoder2 seeds: it does not separate.)
 
 A sequence is padded to one fixed length, and its served tokens to one
 fixed count, so each cell compiles one program per precision whatever the
 request; causal attention keeps the padding out of every position that is
-read.  Layers run one at a time inside a scan, and queries in
-blocks, so the reference fits beside the served weights."""
+read.  Queries run in blocks, so the reference fits beside the served
+weights."""
 
 from __future__ import annotations
 
@@ -33,15 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .shape import Shape
-
 Q_BLOCK = 512
-
-
 FP8_MAX = 448.0     # largest finite float8_e4m3fn
 
 
-def _quant(x, axis):
+def quant(x, axis):
     """fp8 (e4m3) fake-quantization scaled along ``axis`` (the contraction
     axis): exact fp8 values times one float32 scale per remaining index."""
     scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / FP8_MAX
@@ -50,17 +42,17 @@ def _quant(x, axis):
     return q * scale
 
 
-def _linear(x, w, spec, contract_w, control):
+def linear(x, w, spec, contract_w, control):
     """``einsum(spec, x, w)`` contracting the last axis of ``x`` with axes
     ``contract_w`` of ``w``."""
     w = w.astype(jnp.float32)
     if control:
-        x = _quant(x, -1)
-        w = _quant(w, contract_w)
+        x = quant(x, -1)
+        w = quant(w, contract_w)
     return jnp.einsum(spec, x, w)
 
 
-def _norm(s: Shape, p, x):
+def norm(s, p, x):
     if s.norm == "rms":
         y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + s.norm_eps)
         return y * p["scale"].astype(jnp.float32)
@@ -70,7 +62,7 @@ def _norm(s: Shape, p, x):
     return y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: [S, heads, D]; rotate-half rotary embedding."""
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
@@ -80,7 +72,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(s: Shape, q, k, v):
+def attention(s, q, k, v):
     """Causal GQA attention, queries in blocks: q [S, H, D], k/v [S, Kv, D]."""
     n = q.shape[0]
     g = s.heads // s.kv_heads
@@ -97,58 +89,26 @@ def _attention(s: Shape, q, k, v):
     return jnp.concatenate(outs).reshape(n, s.heads, s.head_dim)
 
 
-def _mlp(s: Shape, p, x, control):
-    up = _linear(x, p["w_up"], "sd,df->sf", 0, control)
-    if s.gated:
-        gate = _linear(x, p["w_gate"], "sd,df->sf", 0, control)
-        h = jax.nn.silu(gate) * up
-    else:
-        h = jax.nn.gelu(up, approximate=True)
-    return _linear(h, p["w_down"], "sf,fd->sd", 0, control)
-
-
-@functools.partial(jax.jit, static_argnames=("s", "control"))
-def _logits(s: Shape, weights, tokens, idx, *, control: bool):
-    pos = jnp.arange(tokens.shape[0])
-    x = weights["embed"][tokens].astype(jnp.float32)
-
-    def layer(x, p):
-        h = _norm(s, p["ln1"], x)
-        a = p["attn"]
-        q = _rope(_linear(h, a["wq"], "sd,dhk->shk", 0, control), pos,
-                  s.rope_theta)
-        k = _rope(_linear(h, a["wk"], "sd,dhk->shk", 0, control), pos,
-                  s.rope_theta)
-        v = _linear(h, a["wv"], "sd,dhk->shk", 0, control)
-        o = _attention(s, q, k, v).reshape(tokens.shape[0], -1)
-        wo = a["wo"].reshape(s.heads * s.head_dim, s.d_model)
-        x = x + _linear(o, wo, "sk,kd->sd", 0, control)
-        x = x + _mlp(s, p["mlp"], _norm(s, p["ln2"], x), control)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, weights["groups"][0])
-    x = _norm(s, weights["ln_f"], x[idx])
-    return _linear(x, weights["head"], "nd,dv->nv", 0, control)
-
-
-@functools.partial(jax.jit, static_argnames=("s",))
-def _gaps(s: Shape, weights, tokens, idx, judged):
-    ref = _logits(s, weights, tokens, idx, control=False)
+@functools.partial(jax.jit, static_argnames=("logits", "s"))
+def _gaps(logits, s, weights, tokens, idx, judged):
+    ref = logits(s, weights, tokens, idx, control=False)
     return ref.max(axis=1) - jnp.take_along_axis(ref, judged[:, None], 1)[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("s",))
-def _control_best(s: Shape, weights, tokens, idx):
-    return _logits(s, weights, tokens, idx, control=True).argmax(axis=1)
+@functools.partial(jax.jit, static_argnames=("logits", "s"))
+def _control_best(logits, s, weights, tokens, idx):
+    return logits(s, weights, tokens, idx, control=True).argmax(axis=1)
 
 
-def served_gaps(s: Shape, weights, prompt, served, *, length: int,
+def served_gaps(logits, s, weights, prompt, served, *, length: int,
                 rows: int, control: bool = False) -> np.ndarray:
     """For each served token: the reference's largest logit at that
     position less its logit of the token served there (0 where they agree).
     With ``control`` the token judged is the one the control puts first at
     that position, given the same prompt and served tokens.  The sequence
-    is padded to ``length`` positions and the served tokens to ``rows``."""
+    is padded to ``length`` positions and the served tokens to ``rows``.
+    ``logits(s, weights, tokens, idx, *, control)`` is the family's jitted
+    forward, read at the rows ``idx`` of the padded sequence."""
     n = len(served)
     seq = np.zeros((length,), np.int32)
     seq[:len(prompt) + n - 1] = np.concatenate(
@@ -162,6 +122,6 @@ def served_gaps(s: Shape, weights, prompt, served, *, length: int,
     seq, idx, tok = jnp.asarray(seq), jnp.asarray(idx), jnp.asarray(tok)
     with jax.default_matmul_precision("highest"):
         if control:
-            tok = _control_best(s, weights, seq, idx)
-        gaps = _gaps(s, weights, seq, idx, tok)
+            tok = _control_best(logits, s, weights, seq, idx)
+        gaps = _gaps(logits, s, weights, seq, idx, tok)
     return np.asarray(gaps)[:n]

@@ -1,11 +1,14 @@
-"""CPU tests of the benchmark's yardstick: discovery by name, the traffic
-arithmetic, the work counts, the peaks table, the trace reduction on a
-trace recorded on a TPU v5 lite, and the correctness check (its control
-and a broken timed path) driven end to end at a small size."""
+"""CPU tests of the benchmark's yardstick: discovery by name (an
+architecture family among it), the traffic arithmetic, the work counts,
+the peaks table, the trace reduction on a trace recorded on a TPU v5 lite,
+the dense family against values pinned before it moved, and the
+correctness check (its control and a broken timed path) driven end to end
+at a small size."""
 
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import json
 import os
 import shutil
@@ -17,15 +20,15 @@ import numpy as np
 import pytest
 
 from bench import cells, harness, loadgen, peaks, trace, work
-from bench.shape import Shape
 
 ROOT = cells.ROOT
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
 DATA = Path(__file__).resolve().parent / "data"
 
-YI = Shape.from_config(json.loads((ROOT / "bench/configs/yi-6b.json")
-                                  .read_text()))
+DENSE = cells.load_family("dense")
+YI = DENSE.shape_from_config(json.loads((ROOT / "bench/configs/yi-6b.json")
+                                        .read_text()))
 
 
 # ------------------------------------------------------------- the contract
@@ -42,6 +45,7 @@ def test_benchmark_json_names_files_and_layers():
         conf = json.loads((ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"] and conf["source"] == c["source"]
         assert sorted(conf["reduced"]) == sorted(c["reduced"])
+        assert (ROOT / "bench/families" / f"{conf['family']}.py").exists()
     e2e = {m["name"] for m in bench["end_to_end"]}
     assert "setup_s" in e2e
     for m in bench["end_to_end"]:
@@ -96,9 +100,9 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
 @pytest.mark.parametrize("name", ["yi6b.chat", "sc2.complete"])
 def test_cell_configs_state_published_widths(name):
     cell = cells.find_cell(name)
-    s = Shape.from_config(cell.config)
+    s = cell.family.shape_from_config(cell.config)
     assert s.head_dim == 128 and s.dtype == "bfloat16"
-    cfg = harness.program_config(cell.config, s)
+    cfg = cell.family.program_config(cell.config, s)
     assert cfg.num_layers == s.layers
     assert cell.config["check"]["widest_gap_logits"] > 0
 
@@ -106,8 +110,9 @@ def test_cell_configs_state_published_widths(name):
 def test_program_config_refuses_a_width_that_differs():
     conf = json.loads((ROOT / "bench/configs/yi-6b.json").read_text())
     conf["intermediate_size"] = 11000
+    family = cells.load_family(conf["family"])
     with pytest.raises(ValueError):
-        harness.program_config(conf, Shape.from_config(conf))
+        family.program_config(conf, family.shape_from_config(conf))
 
 
 # ------------------------------------------------------------------- peaks
@@ -129,7 +134,7 @@ def test_work_matches_hand_counts_for_one_packed_tick():
     assert work.attention_flops(YI, segs) == 4 * 32 * 128 * keys
     per_layer = (4096 * 4096 + 2 * 4096 * 512 + 4096 * 4096
                  + 3 * 4096 * 11008)
-    assert work.matmul_flops_per_token(YI) == 2 * 32 * per_layer
+    assert YI.matmul_flops_per_token() == 2 * 32 * per_layer
     assert work.head_flops(YI, 8) == 2 * 4096 * 64000 * 8
     assert work.step_flops(YI, segs, 8) == (
         16 * 2 * 32 * per_layer + 32 * 4 * 32 * 128 * keys
@@ -140,6 +145,38 @@ def test_work_matches_hand_counts_for_one_packed_tick():
     t, bound = work.attention_min_seconds(YI, segs, 197e12, 819e9)
     assert bound == "memory"
     assert t == pytest.approx(2 * (q_o + kv) / 819e9)
+
+
+# ---------------------------------------------------------------- families
+PINS = json.loads((DATA / "dense_pins.json").read_text())
+
+
+def _leaf_digests(tree) -> dict:
+    import jax
+    return {jax.tree_util.keystr(path): f"{x.dtype}{list(x.shape)} "
+            + hashlib.sha256(np.asarray(x).tobytes()).hexdigest()
+            for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.mark.parametrize("name", ["yi6b.chat", "sc2.complete"])
+def test_dense_family_matches_values_pinned_before_the_move(name):
+    """The same seed gives the same weight tree, bit for bit, and the same
+    reference and fp8-control gaps as the dense code gave before it moved
+    into ``bench/families/dense.py``."""
+    pin = PINS["cells"][name]
+    cell = tiny_cell(name)
+    family = cell.family
+    s = family.shape_from_config(cell.config)
+    weights = family.init_weights(s, PINS["seed"])
+    assert _leaf_digests(weights) == pin["leaves"]
+    rng = np.random.default_rng(PINS["prompt_rng"])
+    prompt = rng.integers(0, s.vocab, PINS["prompt_tokens"]).astype(np.int32)
+    served = rng.integers(0, s.vocab, PINS["served_tokens"]).astype(np.int32)
+    for key, control in (("reference_gaps", False), ("control_gaps", True)):
+        gaps = family.served_gaps(s, weights, prompt, served,
+                                  length=PINS["length"], rows=PINS["rows"],
+                                  control=control)
+        assert [float(g) for g in gaps] == pin[key]
 
 
 # ----------------------------------------------------------------- traffic
@@ -342,10 +379,10 @@ def test_union_and_names():
 
 
 # ----------------------------------------------------------- whole runs
-def tiny_cell(name="yi6b.chat") -> cells.Cell:
+def tiny_cell(name="yi6b.chat", root=ROOT) -> cells.Cell:
     """The cell's traffic and serving path at a size the CPU runs in
     seconds: two layers of width 64."""
-    cell = cells.find_cell(name)
+    cell = cells.find_cell(name, root=root)
     conf = dict(cell.config, hidden_size=64, intermediate_size=128,
                 num_attention_heads=4, num_key_value_heads=2,
                 num_hidden_layers=2, vocab_size=512, head_dim=16)
@@ -427,6 +464,59 @@ def test_a_broken_timed_path_fails_the_check(fault):
     assert not line["correct"]
     gap = line["checks"]["widest_gap_logits"]
     assert gap["value"] > gap["limit"]
+
+
+TOY_FAMILY = '''"""The dense family under another name, counting the calls the
+harness makes of it."""
+from pathlib import Path
+
+from bench import cells
+
+dense = cells.load_family("dense", Path(__file__).resolve().parents[2])
+calls = {"init_weights": 0, "served_gaps": 0}
+shape_from_config = dense.shape_from_config
+program_config = dense.program_config
+
+
+def init_weights(shape, seed):
+    calls["init_weights"] += 1
+    return dense.init_weights(shape, seed)
+
+
+def served_gaps(shape, weights, prompt, served, **kw):
+    calls["served_gaps"] += 1
+    return dense.served_gaps(shape, weights, prompt, served, **kw)
+'''
+
+
+def test_a_new_family_is_new_files_only(tmp_path):
+    """A family file, a configuration that names it and the entries in
+    BENCHMARK.json make a cell that runs, with no existing file changed;
+    the run's weights and reference come from the new family."""
+    root = tmp_path / "checkout"
+    shutil.copytree(ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "data"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    (root / "bench/families/toy.py").write_text(TOY_FAMILY)
+    conf = json.loads((ROOT / "bench/configs/yi-6b.json").read_text())
+    conf.update(name="yi-6b.toy", family="toy")
+    (root / "bench/configs/yi-6b.toy.json").write_text(json.dumps(conf))
+    bench = cells.load_benchmark()
+    bench["configs"].append({"name": "yi-6b.toy", "source": conf["source"],
+                             "file": "bench/configs/yi-6b.toy.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "toy.chat", "config": "yi-6b.toy",
+                               "traffic": "chat", "chips": 1, "why": "t"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = tiny_cell("toy.chat", root=root)
+    assert Path(cell.family.__file__) == root / "bench/families/toy.py"
+    assert Path(cell.family.dense.__file__) == root / "bench/families/dense.py"
+    line = _run(cell)
+    assert line["correct"] and line["checks"]["tokens_checked_min"]["value"]
+    assert cell.family.calls["init_weights"] == 1
+    assert cell.family.calls["served_gaps"] >= 1
+    assert all(p.read_bytes() == b for p, b in before.items())
 
 
 def test_run_refuses_without_a_tpu_and_prints_no_result():

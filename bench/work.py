@@ -1,5 +1,8 @@
 """The work a tick needs, from shapes alone: the FLOPs and bytes of the live
 segments it carries, not of what the compiled program pads or masks.
+Attention is counted here for every family, since each has ordinary
+attention layers; the rest of a token's matmuls is the family's own count,
+``shape.matmul_flops_per_token()`` (``bench/families/<family>.py``).
 
 A segment is ``(start, n)``: ``n`` tokens of one request at positions
 ``start .. start + n - 1``, each attending causally to every earlier
@@ -7,8 +10,6 @@ position of its own request and to itself.  A decode rider is ``(pos, 1)``.
 Bytes are counted at 2 per element (bf16 operands)."""
 
 from __future__ import annotations
-
-from .shape import Shape
 
 ELEM = 2
 
@@ -18,20 +19,12 @@ def _keys(start: int, n: int) -> int:
     return n * start + n * (n + 1) // 2
 
 
-def matmul_flops_per_token(s: Shape) -> int:
-    """Projections and MLP of every layer, per token (2 FLOPs a MAC)."""
-    attn = s.d_model * (s.heads + 2 * s.kv_heads) * s.head_dim \
-        + s.heads * s.head_dim * s.d_model
-    mlp = (3 if s.gated else 2) * s.d_model * s.d_ff
-    return 2 * s.layers * (attn + mlp)
-
-
-def attention_flops(s: Shape, segments) -> int:
+def attention_flops(s, segments) -> int:
     """QK^T and PV of one layer over the live segments."""
     return 4 * s.heads * s.head_dim * sum(_keys(a, n) for a, n in segments)
 
 
-def attention_bytes(s: Shape, segments) -> int:
+def attention_bytes(s, segments) -> int:
     """Least bytes one layer's attention kernel must move: each query read
     and each output written once, and each segment's K and V context
     (``start + n`` positions) read once."""
@@ -40,20 +33,20 @@ def attention_bytes(s: Shape, segments) -> int:
     return ELEM * (q_and_o + kv)
 
 
-def head_flops(s: Shape, sampled_rows: int) -> int:
+def head_flops(s, sampled_rows: int) -> int:
     return 2 * s.d_model * s.vocab * sampled_rows
 
 
-def step_flops(s: Shape, segments, sampled_rows: int) -> int:
+def step_flops(s, segments, sampled_rows: int) -> int:
     """Everything one tick needs: matmuls for its live tokens, attention
     over live context in every layer, the head at the rows it samples."""
     tokens = sum(n for _, n in segments)
-    return (matmul_flops_per_token(s) * tokens
+    return (s.matmul_flops_per_token() * tokens
             + s.layers * attention_flops(s, segments)
             + head_flops(s, sampled_rows))
 
 
-def attention_min_seconds(s: Shape, segments, peak_flops: float,
+def attention_min_seconds(s, segments, peak_flops: float,
                           peak_bytes_per_s: float) -> tuple[float, str]:
     """Least time one layer's attention call can take on the chip, and the
     bound that sets it (``"compute"`` or ``"memory"``)."""
